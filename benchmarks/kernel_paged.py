@@ -79,9 +79,9 @@ def parity(quick: bool):
     for B, Hq, Hkv, D, page, npages, npool in shapes:
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)),
+        kp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)),
+        vp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)),
                          jnp.float32)
         bt = jnp.asarray(rng.integers(0, npool, (B, npages)), jnp.int32)
         cl = jnp.asarray(

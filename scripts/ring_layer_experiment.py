@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.dryrun import collective_bytes
 from repro.models.attention import flash_attention
-from repro.models.ring_attention import ring_flash_attention, shard_map_compat
+from repro.models.ring_attention import ring_flash_attention
 
 B, S, H, D, DM, DFF = 32, 32768, 32, 128, 4096, 11008
 MESH = jax.make_mesh((16, 16), ("data", "model"))
@@ -55,9 +55,9 @@ def layer_ring(x, wq, wk, wv, wo, w1, w2):
     xs = P("data", "model", None)
     ws = P(*([None] * 3))
     w2s = P(None, None)
-    return shard_map_compat(inner, mesh=MESH,
-                            in_specs=(xs, ws, ws, ws, ws, w2s, w2s),
-                            out_specs=xs)(x, wq, wk, wv, wo, w1, w2)
+    return jax.shard_map(inner, mesh=MESH,
+                         in_specs=(xs, ws, ws, ws, ws, w2s, w2s),
+                         out_specs=xs)(x, wq, wk, wv, wo, w1, w2)
 
 
 def measure(fn, shardings):

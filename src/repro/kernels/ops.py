@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with ``interpret=True`` — the
-kernel body executes with real block/grid semantics so correctness of the
-BlockSpec tiling is what's validated; on TPU the same call lowers through
-Mosaic.
+On a TPU backend the kernels lower through Mosaic.  On the CPU backend
+(the test suite, ``JAX_PLATFORMS=cpu``) they run with ``interpret=True``:
+the kernel body executes with real block/grid semantics, so the BlockSpec
+indexing is checked but not Mosaic's tiling rules.  Any other backend
+raises instead of silently interpreting.
 """
 from __future__ import annotations
 
@@ -24,7 +25,11 @@ DEFAULT_PAGES_PER_SPLIT = 4
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu (Mosaic) or cpu "
+                           f"(interpreted), not on {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

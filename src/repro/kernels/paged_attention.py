@@ -4,8 +4,9 @@
 TPU adaptation of vLLM's PagedAttention: the per-request block table is
 *scalar-prefetched* so the kv-pool BlockSpec index maps can chase the
 indirection while the previous tile is still streaming HBM→VMEM.  Pool
-blocks are (page_size × head_dim) VMEM tiles; flash-style (m, l, acc)
-statistics for the G query heads of a group live in VMEM scratch.
+blocks are (page_size × head_dim) VMEM tiles of one kv head; flash-style
+(m, l, acc) statistics for the G query heads of a group live in VMEM
+scratch.
 
 Three generalizations over the original one-page-at-a-time kernel:
 
@@ -26,11 +27,14 @@ Three generalizations over the original one-page-at-a-time kernel:
 
 Inputs:
     q            (B, Hq, D)        one token per query row
-    k_pool/v_pool(P, page, Hkv, D) global paged KV pools (fp or int8)
+    k_pool/v_pool(P, Hkv, page, D) global paged KV pools (fp or int8),
+                                   head-major: Mosaic needs a block's last
+                                   two dims to be (page, D) tiles, so the
+                                   squeezed head axis cannot sit between
     block_tables (T, n_pages)      int32 pool-page ids per table row
     ctx_lens     (B,)              int32 valid context length per query row
     row_map      (B,) or None      int32 table row per query row
-    k/v_scale    (P, page, Hkv)    bf16 dequant scales (int8 pools only)
+    k/v_scale    (P, Hkv, page)    bf16 dequant scales (int8 pools only)
 
 Fully masked rows (``ctx_lens[b] == 0``) return exact zeros: masked
 scores contribute ``p = 0`` (an explicit mask multiply — NEG_INF is
@@ -51,7 +55,7 @@ NEG_INF = -1e30
 
 def _validate(q, k_pool, block_tables, row_map, k_scale, v_scale):
     B, Hq, _ = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     if Hq % Hkv != 0:
         raise ValueError(
             f"paged attention: Hq={Hq} query heads do not group evenly "
@@ -174,7 +178,7 @@ def _prep(q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
     """Shared shape plumbing of both launch variants."""
     _validate(q, k_pool, block_tables, row_map, k_scale, v_scale)
     B, Hq, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[1]
     G = Hq // Hkv
     if row_map is None:
         row_map = jnp.arange(B, dtype=jnp.int32)
@@ -197,7 +201,7 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens, *,
     B, Hq, D, Hkv, G, scalars, inputs = _prep(
         q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
         v_scale)
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     Dv = v_pool.shape[-1]
     n_pages = block_tables.shape[1]
     quant = k_scale is not None
@@ -210,15 +214,15 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens, *,
         return (b, h, 0, 0)
 
     def kv_index(b, h, j, tables, rows, ctx):
-        return (tables[rows[b], j], 0, h, 0)
+        return (tables[rows[b], j], h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, G, D), q_index),
-        pl.BlockSpec((None, page, None, D), kv_index),
-        pl.BlockSpec((None, page, None, Dv), kv_index),
+        pl.BlockSpec((None, None, page, D), kv_index),
+        pl.BlockSpec((None, None, page, Dv), kv_index),
     ]
     if quant:
-        in_specs += [pl.BlockSpec((None, page, None, 1), kv_index)] * 2
+        in_specs += [pl.BlockSpec((None, None, page, 1), kv_index)] * 2
     o_spec = pl.BlockSpec((None, None, G, Dv), q_index)
     o_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype)
     if return_stats:
@@ -264,7 +268,7 @@ def paged_attention_splitk_pallas(q, k_pool, v_pool, block_tables,
     B, Hq, D, Hkv, G, scalars, inputs = _prep(
         q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
         v_scale)
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     Dv = v_pool.shape[-1]
     n_pages = block_tables.shape[1]
     quant = k_scale is not None
@@ -282,18 +286,18 @@ def paged_attention_splitk_pallas(q, k_pool, v_pool, block_tables,
         return (b, h, 0, 0)
 
     def kv_index(b, h, s, j, tables, rows, ctx):
-        return (tables[rows[b], s * pages_per_split + j], 0, h, 0)
+        return (tables[rows[b], s * pages_per_split + j], h, 0, 0)
 
     def part_index(b, h, s, j, tables, rows, ctx):
         return (b, h, s, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, G, D), q_index),
-        pl.BlockSpec((None, page, None, D), kv_index),
-        pl.BlockSpec((None, page, None, Dv), kv_index),
+        pl.BlockSpec((None, None, page, D), kv_index),
+        pl.BlockSpec((None, None, page, Dv), kv_index),
     ]
     if quant:
-        in_specs += [pl.BlockSpec((None, page, None, 1), kv_index)] * 2
+        in_specs += [pl.BlockSpec((None, None, page, 1), kv_index)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, Hkv, n_splits, pages_per_split),

@@ -14,11 +14,14 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0):
 def paged_attention_ref(q, k_pool, v_pool, block_tables, ctx_lens):
     """Gather pages into contiguous caches, then run masked attention."""
     B, Hq, D = q.shape
-    n_pool, page, Hkv, _ = k_pool.shape
+    n_pool, Hkv, page, _ = k_pool.shape
     n_pages = block_tables.shape[1]
-    # (B, n_pages, page, Hkv, D) -> (B, S, Hkv, D)
-    kc = k_pool[block_tables].reshape(B, n_pages * page, Hkv, -1)
-    vc = v_pool[block_tables].reshape(B, n_pages * page, Hkv, -1)
+
+    def gather(pool):     # (B, n_pages, Hkv, page, D) -> (B, S, Hkv, D)
+        return pool[block_tables].swapaxes(2, 3).reshape(
+            B, n_pages * page, Hkv, -1)
+
+    kc, vc = gather(k_pool), gather(v_pool)
     out = []
     for b in range(B):                            # oracle: clarity over speed
         valid = jnp.arange(n_pages * page) < ctx_lens[b]

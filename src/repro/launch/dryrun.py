@@ -95,8 +95,6 @@ def run_pair(arch: str, shape_name: str, mesh_kind: str, verbose=True,
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     result = {
         "arch": arch, "shape": shape_name, "mesh": mesh_kind,

@@ -23,19 +23,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.attention import NEG_INF, _group_heads
 
-# jax.shard_map is top-level only on newer jax; 0.4.x ships it under
-# jax.experimental
-if hasattr(jax, "shard_map"):
-    shard_map_compat = jax.shard_map
-else:                                   # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as shard_map_compat
-
-
-def _axis_size(axis_name: str) -> int:
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)  # 0.4.x: lookup yields the size
-
 
 def ring_flash_attention(q, k, v, axis_name: str, *, causal: bool = True):
     """Local shards: q (B, S_loc, Hq, D); k/v (B, S_loc, Hkv, D[v]).
@@ -43,7 +30,7 @@ def ring_flash_attention(q, k, v, axis_name: str, *, causal: bool = True):
     Returns the local output shard (B, S_loc, Hq, Dv).  Must run inside
     ``shard_map`` with the sequence dim sharded over ``axis_name``.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S_loc, Hq, Dk = q.shape
     Hkv = k.shape[2]
@@ -53,18 +40,12 @@ def ring_flash_attention(q, k, v, axis_name: str, *, causal: bool = True):
     scale = Dk ** -0.5
     q_pos = idx * S_loc + jnp.arange(S_loc)
 
-    # pvary: accumulators must carry the same varying-mesh-axes type as
-    # the data they merge with (q may vary over more axes than the ring's)
-    try:
-        vary_axes = tuple(jax.typeof(q).vma)
-    except Exception:   # noqa: BLE001 — older jax without vma typing
-        vary_axes = (axis_name,)
+    # accumulators must carry the same varying-mesh-axes type as the data
+    # they merge with (q may vary over more axes than the ring's)
+    vary_axes = tuple(jax.typeof(q).vma)
 
     def _mk(x):
-        # pvary only exists on jax versions with varying-mesh-axes typing
-        if vary_axes and hasattr(jax.lax, "pvary"):
-            return jax.lax.pvary(x, vary_axes)
-        return x
+        return jax.lax.pcast(x, vary_axes, to="varying") if vary_axes else x
 
     acc0 = _mk(jnp.zeros((B, S_loc, Hkv, G, Dv), jnp.float32))
     m0 = _mk(jnp.full((B, S_loc, Hkv, G), NEG_INF, jnp.float32))
@@ -105,5 +86,5 @@ def ring_attention_sharded(q, k, v, mesh, axis_name: str = "data", *,
     spec = P(None, axis_name, None, None)
     fn = functools.partial(ring_flash_attention, axis_name=axis_name,
                            causal=causal)
-    return shard_map_compat(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(q, k, v)

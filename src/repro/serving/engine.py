@@ -82,8 +82,11 @@ class ServingEngine:
                  prefix_cache: bool = False,
                  kv_quant: bool = False,
                  keep_first_logits: bool = False,
-                 observer=None, admission=None):
+                 observer=None, admission=None, device=None):
         self.cfg = cfg
+        # the jax device holding this replica's params and KV (None: the
+        # default device); the jitted steps run where their inputs live
+        self.device = device
         self.sched = scheduler
         self.max_slots = max_slots
         self.max_len = max_len
@@ -136,7 +139,7 @@ class ServingEngine:
         self.rng = jax.random.key(seed)
         if params is None:
             params = init_params(jax.random.key(seed + 1), cfg)
-        self.params = params
+        self.params = self._place(params)
         self.backend = backend
         self.k_scales = self.v_scales = None
         if backend == "paged":
@@ -153,15 +156,15 @@ class ServingEngine:
             self._scratch_page = n_pages
             if kv_quant:
                 (self.k_pools, self.v_pools, self.k_scales,
-                 self.v_scales) = make_pools(
+                 self.v_scales) = self._place(make_pools(
                     cfg.n_layers, n_pages + 1, page_size, cfg.n_kv_heads,
-                    cfg.resolved_head_dim(), quantized=True)
+                    cfg.resolved_head_dim(), quantized=True))
             else:
-                self.k_pools, self.v_pools = make_pools(
+                self.k_pools, self.v_pools = self._place(make_pools(
                     cfg.n_layers, n_pages + 1, page_size, cfg.n_kv_heads,
-                    cfg.resolved_head_dim(), dtype_of(cfg))
+                    cfg.resolved_head_dim(), dtype_of(cfg)))
         else:
-            self.cache = init_cache(cfg, max_slots, max_len)
+            self.cache = self._place(init_cache(cfg, max_slots, max_len))
             # inactive slots decode garbage into slot 0 tokens — masked out
         if prefix_cache:
             # shared-prefix radix KV cache (DESIGN.md §9): only the paged
@@ -184,6 +187,11 @@ class ServingEngine:
         self.iterations = 0
 
     # -- helpers ----------------------------------------------------------------
+    def _place(self, tree):
+        """Commit arrays (or host buffers) to this replica's device."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
+
     def now(self) -> float:
         return self.t_model
 
@@ -380,10 +388,9 @@ class ServingEngine:
             tokens += [0] * (n_pad - n_rows)   # on the scratch page (all
             ctx += [0] * (n_pad - n_rows)      # write identical values);
             rmap += [n_t] * (n_pad - n_rows)   # ctx=0 => fully masked
-        step_args = (self.params, jnp.asarray(np.asarray(tokens, np.int32)),
-                     jnp.asarray(np.asarray(ctx, np.int32)),
-                     jnp.asarray(bt),
-                     jnp.asarray(np.asarray(rmap, np.int32)))
+        step_args = (self.params, *self._place((
+            np.asarray(tokens, np.int32), np.asarray(ctx, np.int32), bt,
+            np.asarray(rmap, np.int32))))
         if self.kv_quant:
             (logits, self.k_pools, self.v_pools, self.k_scales,
              self.v_scales) = _paged_decode_step(
@@ -431,7 +438,7 @@ class ServingEngine:
             self.cache["pos"] = self.cache["pos"].at[slot].set(
                 req.prompt_len + req._vlm_prefix)
         req._pcache = None
-        req._next_token = int(jnp.argmax(row))
+        req._next_token = int(np.argmax(row))
         if self.keep_first_logits:
             req._first_row = np.asarray(row, np.float32)
         req._pos = req.prompt_len + req._vlm_prefix
@@ -650,10 +657,10 @@ def _paged_decode_step(params, tokens, ctx_lens, block_tables, row_map,
         if quant:
             k, k_s = quantize_kv(k)
             v, v_s = quantize_kv(v)
-            ks = ks.at[page_idx, slot_idx].set(k_s)
-            vs = vs.at[page_idx, slot_idx].set(v_s)
-        kp = kp.at[page_idx, slot_idx].set(k)
-        vp = vp.at[page_idx, slot_idx].set(v)
+            ks = ks.at[page_idx, :, slot_idx].set(k_s)
+            vs = vs.at[page_idx, :, slot_idx].set(v_s)
+        kp = kp.at[page_idx, :, slot_idx].set(k)          # head-major pages
+        vp = vp.at[page_idx, :, slot_idx].set(v)
         out = paged_attention(q, kp, vp, block_tables, pos + 1,
                               row_map=row_map, k_scale=ks, v_scale=vs)
         y = jnp.einsum("bhk,hkd->bd", out, lp["attn"]["wo"])[:, None]

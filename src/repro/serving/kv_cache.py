@@ -1,7 +1,8 @@
 """Paged KV-cache block manager (host side) + pool tensors (device side).
 
 vLLM-style indirection adapted to TPU tiles (DESIGN.md §3): the pools are
-(n_pages, page_size, n_kv_heads, head_dim) arrays per layer; requests own
+head-major (n_pages, n_kv_heads, page_size, head_dim) arrays per layer, so
+one kv head's page is a (page_size, head_dim) tile; requests own
 lists of page ids; block tables are dense int32 matrices handed to the
 Pallas paged-attention kernel (0-padded — padding pages are masked by
 ``ctx_lens`` inside the kernel).
@@ -196,14 +197,14 @@ class PagePool:
 
 def make_pools(n_layers: int, n_pages: int, page_size: int, n_kv_heads: int,
                head_dim: int, dtype=jnp.float32, quantized: bool = False):
-    """Stacked per-layer K/V pools: (L, n_pages, page, Hkv, D).
+    """Stacked per-layer K/V pools: (L, n_pages, Hkv, page, D).
 
     ``quantized=True`` (DESIGN.md §16) returns int8 payload pools plus
-    per-(slot, head) bf16 scale pools (L, n_pages, page, Hkv) — the
+    per-(slot, head) bf16 scale pools (L, n_pages, Hkv, page) — the
     ``quantize_kv`` contract (scales are the payload shape minus the
     trailing head_dim axis).  Zero-initialized scales are safe: an unwritten
     slot dequantizes to exact zeros."""
-    shape = (n_layers, n_pages, page_size, n_kv_heads, head_dim)
+    shape = (n_layers, n_pages, n_kv_heads, page_size, head_dim)
     if quantized:
         return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
                 jnp.zeros(shape[:-1], jnp.bfloat16),
@@ -213,11 +214,10 @@ def make_pools(n_layers: int, n_pages: int, page_size: int, n_kv_heads: int,
 
 def scatter_prefill(pool, layer_caches, pages: List[int], page_size: int,
                     n_tokens: Optional[int] = None):
-    """Scatter contiguous K or V rows (L, S, Hkv, D) into ``pages``,
-    zero-padding the final partial page.  The one implementation of the
-    page-boundary pad-and-set logic — shared by ``write_prefill_to_pool``
-    and the engine's non-chunked install path.  ``n_tokens`` caps the
-    copied prefix (the contiguous cache may be wider than the prompt)."""
+    """Scatter contiguous K or V rows (L, S, Hkv, D) into ``pages`` of a
+    head-major pool, zero-padding the final partial page (the engine's
+    non-chunked install path).  ``n_tokens`` caps the copied prefix (the
+    contiguous cache may be wider than the prompt)."""
     S = layer_caches.shape[1]
     if n_tokens is not None:
         S = min(S, n_tokens)
@@ -230,20 +230,5 @@ def scatter_prefill(pool, layer_caches, pages: List[int], page_size: int,
         if hi - lo < page_size:
             chunk = jnp.pad(chunk, ((0, 0), (0, page_size - (hi - lo)),
                                     (0, 0), (0, 0)))
-        pool = pool.at[:, pg].set(chunk)
+        pool = pool.at[:, pg].set(chunk.swapaxes(1, 2))
     return pool
-
-
-def write_prefill_to_pool(pool, layer_caches, pages: List[int],
-                          page_size: int):
-    """Scatter a request's contiguous prefill K (L, S, Hkv, D) into its
-    pages.  Host-side op (np/at-set); done once per admitted request."""
-    return scatter_prefill(pool, layer_caches, pages, page_size)
-
-
-def write_token_to_pool(pool, kv_token, pages: List[int], pos: int,
-                        page_size: int):
-    """Write one decode token's K or V (L, Hkv, D) at absolute position."""
-    page = pages[pos // page_size]
-    slot = pos % page_size
-    return pool.at[:, page, slot].set(kv_token)

@@ -39,8 +39,8 @@ def test_flash_kernel(S, Hq, Hkv, D, causal, window, bq, bkv, dtype, rng):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_kernel(B, Hq, Hkv, D, page, npages, npool, dtype, rng):
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), dtype)
-    kp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)), dtype)
+    kp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)), dtype)
     bt = jnp.asarray(rng.integers(0, npool, (B, npages)), jnp.int32)
     cl = jnp.asarray(rng.integers(1, npages * page, (B,)), jnp.int32)
     out = paged_attention(q, kp, vp, bt, cl)

@@ -54,6 +54,60 @@ def test_build_step_lowers_on_host_mesh(shape_name, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes >= 0
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     assert cost.get("flops", 0) > 0
+
+
+# -- serving launcher ---------------------------------------------------------
+
+def test_compile_cache_keeps_env_dir(monkeypatch, tmp_path):
+    """A set JAX_COMPILATION_CACHE_DIR is JAX's own to read: the helper
+    reports it and sets no other path."""
+    from repro.launch import serve
+    calls = []
+    monkeypatch.setattr(serve.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert serve.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+
+
+def test_compile_cache_defaults_to_repo_dir(monkeypatch):
+    from repro.launch import serve
+    calls = []
+    monkeypatch.setattr(serve.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = serve.enable_compile_cache()
+    assert path == str(serve.REPO_ROOT / ".jax_cache")
+    assert (serve.REPO_ROOT / "pyproject.toml").exists()
+    assert calls == [("jax_compilation_cache_dir", path)]
+
+
+@pytest.mark.parametrize("arch,backend", [("granite-3-2b", "paged"),
+                                          ("mamba2-2.7b", "slots")])
+def test_build_engine_picks_paged_where_chunkable(arch, backend):
+    from repro.core import make_scheduler
+    from repro.launch.serve import build_engine
+    from repro.serving.costmodel import CostModel
+    cfg = SMOKE_FACTORIES[arch]()
+    eng = build_engine(cfg, make_scheduler("fcfs"), CostModel(cfg),
+                       max_slots=2, max_len=64)
+    assert eng.backend == backend
+
+
+def test_engine_device_pins_params_pools_and_step():
+    """``device=`` commits params and pools to that device, and the
+    fused step keeps them there."""
+    from repro.core import Request, make_scheduler
+    from repro.serving.engine import ServingEngine
+    dev = jax.devices()[-1]
+    cfg = SMOKE_FACTORIES["granite-3-2b"]()
+    eng = ServingEngine(cfg, make_scheduler("fcfs"), max_slots=2,
+                        max_len=64, backend="paged", device=dev)
+    reqs = [Request(rid=i, client="c", arrival=0.0, prompt_len=12,
+                    output_len=3, keywords=("chat",)) for i in range(2)]
+    assert len(eng.run(reqs)) == 2
+    held = {d for a in (*jax.tree.leaves(eng.params), eng.k_pools,
+                        eng.v_pools) for d in a.devices()}
+    assert held == {dev}
+    assert all(a.committed for a in (eng.k_pools, eng.v_pools))

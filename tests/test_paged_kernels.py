@@ -28,8 +28,8 @@ pytestmark = pytest.mark.kernels
 def make_case(seed, B, Hq, Hkv, D, page, npages, npool, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, Hq, D)), dtype)
-    kp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.standard_normal((npool, page, Hkv, D)), dtype)
+    kp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((npool, Hkv, page, D)), dtype)
     bt = jnp.asarray(rng.integers(0, npool, (B, npages)), jnp.int32)
     return q, kp, vp, bt
 
@@ -240,3 +240,18 @@ def test_kv_quant_doubles_default_budget():
                       max_len=64, backend="paged", chunked=True,
                       kv_quant=True)
     assert q.kv_budget == 2 * fp.kv_budget
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """No hidden fallback: the Pallas interpreter runs only on the CPU
+    backend; a backend that is neither cpu nor tpu raises."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

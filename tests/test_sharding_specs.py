@@ -81,8 +81,9 @@ def test_jit_with_specs_on_host_mesh():
     """End-to-end: sharded loss step on the single-device host mesh."""
     from repro.models import loss_fn
     from jax.sharding import NamedSharding
+    from repro.launch.mesh import make_host_mesh
     cfg = SMOKE_FACTORIES["llama2-7b"]()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()
     params = init_params(jax.random.key(0), cfg)
     specs = param_specs(params, cfg, mesh)
     sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
