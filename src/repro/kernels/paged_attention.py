@@ -243,8 +243,11 @@ def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens, *,
             pltpu.VMEM((G, 1), jnp.float32),
         ],
     )
+    # named: the kernel's instruction in a profile is
+    # ``%paged_attention_serial.N``, whatever jit wraps it
     outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(*scalars, *inputs)
+                          interpret=interpret,
+                          name="paged_attention_serial")(*scalars, *inputs)
     if return_stats:
         out, m, l = outs
         return (out.reshape(B, Hq, Dv), m.reshape(B, Hq),
@@ -322,6 +325,7 @@ def paged_attention_splitk_pallas(q, k_pool, v_pool, block_tables,
             jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="paged_attention_splitk",   # ``%paged_attention_splitk.N``
     )(*scalars, *inputs)
     # combine: m = max_s m_s (exact); partials rescale by exp(m_s - m).
     # Splits fully beyond ctx carry (m=NEG_INF, l=0, acc=0) and vanish;

@@ -11,10 +11,15 @@ Two decode backends:
   (``repro.kernels.paged_attention``); the vLLM-style production path for
   uniform dense-GQA stacks (the paper's Llama-2 testbed shape).
 
-Timing uses a dual clock: wall-clock for real measurements and the
-analytic cost model for target-hardware metrics fed back to the
-scheduler (this container's CPU timings are not meaningful for an
-accelerator-bound system).
+Timing: the scheduler plans on the analytic cost model's modeled clock
+(``t_model``, target-hardware seconds), which is also what every
+request's TTFT/latency stamps read; the engine keeps no wall clock of
+its own.  Host wall time is what the caller measures around ``step()``,
+or what the optional ``StepTracer`` (``tracer=``, DESIGN.md §14) records
+span by span: ``engine.step``, ``engine.submit``, the four ``core.*``
+calls and the fused launch's ``step.pack`` / ``step.dispatch`` /
+``step.wait`` / ``step.fetch`` / ``step.widen``, with the step's row,
+logits, queue and KV counters.
 
 Scheduling decisions (admission, ``canSchedule`` KV reservation, the
 chunked-prefill plan, the completion feedback loop) are NOT
@@ -40,7 +45,6 @@ never at admission.  Like the simulator it exposes the replica protocol
 from __future__ import annotations
 
 import heapq
-import time
 from typing import Dict, List, Optional
 
 import jax
@@ -60,6 +64,7 @@ from repro.models.moe import moe_ffn
 from repro.serving.batch_core import BatchConfig, BatchCore
 from repro.serving.costmodel import CostModel
 from repro.serving.kv_cache import PagePool, make_pools, scatter_prefill
+from repro.serving.telemetry import StepTracer
 
 def _next_pow2(n: int) -> int:
     """Static-shape bucketing for the jitted decode step (DESIGN.md §16):
@@ -82,8 +87,11 @@ class ServingEngine:
                  prefix_cache: bool = False,
                  kv_quant: bool = False,
                  keep_first_logits: bool = False,
-                 observer=None, admission=None, device=None):
+                 observer=None, admission=None, device=None,
+                 tracer: Optional[StepTracer] = None):
         self.cfg = cfg
+        # host spans and counters of each iteration (off by default)
+        self.tracer = tracer if tracer is not None else StepTracer()
         # the jax device holding this replica's params and KV (None: the
         # default device); the jitted steps run where their inputs live
         self.device = device
@@ -179,7 +187,6 @@ class ServingEngine:
         #                                     (admission order = sim order)
         self.reserved = self.core.reserved  # alias: core owns KV accounting
         self.t_model = 0.0            # modeled target-hardware clock
-        self.t_wall0 = time.monotonic()
         self.finished: List[Request] = []
         self._prefill_jit: Dict[int, object] = {}
         self._chunk_jit = None
@@ -228,6 +235,10 @@ class ServingEngine:
         return -1
 
     def submit(self, req: Request):
+        with self.tracer.span("engine.submit"):
+            self._submit(req)
+
+    def _submit(self, req: Request):
         # overload-aware admission gate (DESIGN.md §13) — same decision
         # point as Simulator.submit, so sim and engine throttle the
         # identical request set
@@ -348,9 +359,52 @@ class ServingEngine:
         crossings or batch jitter; padding rows write token 0 at pos 0 of
         the sacrificial scratch page and their logits are sliced away.
 
-        Returns ({rid: last-chunk-row logits}, {rid: decode logits})."""
+        Returns ({rid: last-chunk-row logits}, {rid: decode logits}).
+
+        Host spans (``self.tracer``): ``step.pack`` (pages, row and
+        table arrays, padding, placement), ``step.dispatch`` (the jitted
+        call; returns once enqueued), ``step.wait`` (the device's
+        compute), ``step.fetch`` (the bf16 logits to the host),
+        ``step.widen`` (to float32 — exact — and the rows' dicts)."""
         if not plan and not decoding:
             return {}, {}
+        tr = self.tracer
+        with tr.span("step.pack"):
+            step_args, last_row, n_chunk_rows, shape = self._pack_mixed(
+                plan, decoding)
+        with tr.span("step.dispatch"):
+            if self.kv_quant:
+                (logits, self.k_pools, self.v_pools, self.k_scales,
+                 self.v_scales) = _paged_decode_step(
+                    *step_args, self.k_pools, self.v_pools, self.k_scales,
+                    self.v_scales, self.cfg, self.pool.page_size)
+            else:
+                logits, self.k_pools, self.v_pools = _paged_decode_step(
+                    *step_args, self.k_pools, self.v_pools, None, None,
+                    self.cfg, self.pool.page_size)
+        with tr.span("step.wait"):
+            logits.block_until_ready()
+        with tr.span("step.fetch"):
+            logits = np.asarray(logits)
+        with tr.span("step.widen"):
+            logits = logits.astype(np.float32)
+            first_rows = {rid: logits[i] for rid, i in last_row.items()}
+            rows = {r.rid: logits[n_chunk_rows + i]
+                    for i, r in enumerate(decoding)}
+        if tr.on:
+            n_rows, n_pad = shape
+            # the step reads a chunk's row only where it ends the prompt
+            used = sum(req.prefill_done >= req.prompt_len for req, _ in plan)
+            tr.count(rows_real=n_rows, rows_launched=n_pad,
+                     logits_rows_used=used + len(decoding),
+                     logits_rows_fetched=n_pad)
+        return first_rows, rows
+
+    def _pack_mixed(self, plan, decoding: List[Request]):
+        """The fused step's inputs on the device: pages ensured, then
+        tokens, positions, block table and row map, padded to their
+        buckets.  Returns (step args, {rid: its chunk's last row},
+        number of chunk rows, (real rows, launched rows))."""
         tokens: List[int] = []
         ctx: List[int] = []
         rmap: List[int] = []
@@ -391,20 +445,7 @@ class ServingEngine:
         step_args = (self.params, *self._place((
             np.asarray(tokens, np.int32), np.asarray(ctx, np.int32), bt,
             np.asarray(rmap, np.int32))))
-        if self.kv_quant:
-            (logits, self.k_pools, self.v_pools, self.k_scales,
-             self.v_scales) = _paged_decode_step(
-                *step_args, self.k_pools, self.v_pools, self.k_scales,
-                self.v_scales, self.cfg, self.pool.page_size)
-        else:
-            logits, self.k_pools, self.v_pools = _paged_decode_step(
-                *step_args, self.k_pools, self.v_pools, None, None,
-                self.cfg, self.pool.page_size)
-        logits = np.asarray(logits, np.float32)
-        first_rows = {rid: logits[i] for rid, i in last_row.items()}
-        rows = {r.rid: logits[n_chunk_rows + i]
-                for i, r in enumerate(decoding)}
-        return first_rows, rows
+        return step_args, last_row, n_chunk_rows, (n_rows, n_pad)
 
     def _install_prefill(self, req: Request, row):
         """Prompt fully prefilled: make the request decodable.  For the
@@ -480,16 +521,38 @@ class ServingEngine:
         """One continuous-batching iteration (mirrors ``Simulator.step``
         statement for statement — both drive the shared BatchCore).
         Returns #running requests (1 when only quota-blocked queued work
-        exists — the clock still advanced), 0 when idle."""
+        exists — the clock still advanced), 0 when idle.  With the
+        tracer on, the iteration is the ``engine.step`` span and closes
+        one tracer record."""
+        tr = self.tracer
+        with tr.step(self.iterations):
+            n = self._step(tr)
+            if tr.on:
+                self._count_step(tr)
+        return n
+
+    def _count_step(self, tr: StepTracer):
+        """The step's queue level, and on the paged backend its KV pages
+        written against the pages its reservations hold (each one
+        rounded up to whole pages, as it would be allocated)."""
+        tr.level(waiting=sum(len(q) for q in self.sched.queues.values()))
+        if self.backend == "paged":
+            pool = self.pool
+            tr.level(kv_pages_used=pool.n_pages - len(pool.free),
+                     kv_pages_reserved=sum(
+                         map(pool.pages_needed, self.core.reserved.values())))
+
+    def _step(self, tr: StepTracer):
         now = self.now()
         # 1. admission (Algorithm 1 inner loop, the one BatchCore.admit
         #    skip-protocol implementation; slot bookkeeping rides its
         #    callbacks, so sim and engine cannot drift)
-        admitted = self.core.admit(
-            now, len(self.running),
-            has_capacity=lambda: self._free_slot() >= 0,
-            on_admitted=lambda req: self._bind_slot(req,
-                                                    self._free_slot()))
+        with tr.span("core.admit"):
+            admitted = self.core.admit(
+                now, len(self.running),
+                has_capacity=lambda: self._free_slot() >= 0,
+                on_admitted=lambda req: self._bind_slot(req,
+                                                        self._free_slot()))
         if not self.running:
             if not self.sched.has_waiting():
                 return 0
@@ -507,7 +570,8 @@ class ServingEngine:
         #     fairly if the budget would be exceeded — BEFORE any model
         #     work, so victims neither prefill nor decode (and the paged
         #     pool never reaches physical exhaustion)
-        preempted = self.core.prepare_iteration(now, self.running)
+        with tr.span("core.prepare"):
+            preempted = self.core.prepare_iteration(now, self.running)
         for req in preempted:
             self._drop_backend_state(req)
             self.running.remove(req)
@@ -518,7 +582,8 @@ class ServingEngine:
         #    next one).  On the chunked paged backend both go down in ONE
         #    ragged kernel launch (DESIGN.md §16) — the fused pass the
         #    cost model already prices as ``mixed_step_time``.
-        plan = self.core.plan_prefill(self.running)
+        with tr.span("core.plan"):
+            plan = self.core.plan_prefill(self.running)
         decoding = [r for r in self.running if r.state == DECODING]
         if self.backend == "paged" and self.chunked:
             first_rows, rows = self._run_mixed_paged(plan, decoding)
@@ -564,12 +629,13 @@ class ServingEngine:
                 self.pool.free_request(req.rid)
             self.slots[req._slot] = None
 
-        self.core.execute_iteration(
-            now, plan, decoding, t_iter=t_iter, fresh=fresh,
-            firsts=[req for req, _ in done_prefill],
-            admitted=admitted, preempted=preempted,
-            on_first=on_first, on_decode=on_decode,
-            post_complete=post_complete)
+        with tr.span("core.lifecycle"):
+            self.core.execute_iteration(
+                now, plan, decoding, t_iter=t_iter, fresh=fresh,
+                firsts=[req for req, _ in done_prefill],
+                admitted=admitted, preempted=preempted,
+                on_first=on_first, on_decode=on_decode,
+                post_complete=post_complete)
         self.iterations += 1
         return n_running
 

@@ -1,6 +1,6 @@
 """Flight recorder: structured event tracing + replay audit (DESIGN.md §14).
 
-Three layers, zero overhead when off (``BatchCore`` and the drivers
+Four layers, zero overhead when off (``BatchCore`` and the drivers
 guard every hook behind ``if observer is not None``; no observer means
 no calls, no allocations):
 
@@ -22,6 +22,14 @@ no calls, no allocations):
   and the eventual actuals at completion, so prediction accuracy is
   auditable per expert after the fact.
 
+- ``StepTracer`` — the host side of the serving step on the wall
+  clock: named spans (``jax.profiler.TraceAnnotation``, so they land in
+  a profile beside the device's ops) whose durations, with the step's
+  row/logits/queue/KV counters, are kept as one record per engine
+  iteration.  Off by default: a span is then one attribute check and a
+  shared no-op context.  The flight recorder stamps every event with
+  the same clock (``tw``, ``wall()``) beside the modeled ``t``.
+
 - consumers — ``to_chrome_trace`` (Perfetto-loadable Chrome trace
   JSON: one process per replica, one track per account, counter tracks
   for KV/budget/fairness), ``windowed_fairness`` (rolling Jain and the
@@ -40,7 +48,13 @@ exists for timeline export, not for replay.
 from __future__ import annotations
 
 import json
+import time
 from typing import Dict, List, Optional
+
+# the wall clock of the step tracer's records and the flight recorder's
+# ``tw`` stamps, in seconds (monotonic; ``perf_counter_ns`` is the same
+# clock in nanoseconds)
+wall = time.perf_counter
 
 # Request lifecycle event types recorded by FlightRecorder.  Every name
 # here must appear (backtick-quoted) in the DESIGN.md §14 schema table —
@@ -225,6 +239,12 @@ class FlightRecorder(Observer):
     feed the timeline counter tracks and the windowed fairness audit,
     where every-K fidelity is plenty.  Pass ``sample_every=1`` for
     full-fidelity snapshots.
+
+    Every event carries two stamps: ``t``, the modeled clock, which all
+    the consumers below read, and ``tw``, the host's wall clock
+    (``wall()``, seconds) when the hook fired — the ``StepTracer``'s
+    clock, so queue waits and iteration spans line up.  ``rid`` is the
+    ``Request.rid`` the caller submitted.
     """
 
     def __init__(self, sample_every: int = 16):
@@ -250,33 +270,41 @@ class FlightRecorder(Observer):
         rids and expanded here, just before the step's iteration event —
         every requeue happens at the step timestamp, and ``on_requeue``
         is refund-only accounting (commutative with the step's token
-        charges), so replay order is preserved where it matters."""
+        charges), so replay order is preserved where it matters.  A
+        requeue's wall stamp ``tw`` is likewise its step's (the hot hook
+        reads no clock), so ``tw`` never decreases along the log."""
         key = (len(self._log), len(self._requeued))
         if self._mat_key == key:
             return self._mat
         out: List[dict] = []
+        tw = 0.0
         for e in self._log:
             if type(e) is not tuple:
                 out.append(e)
+                tw = e["tw"]
                 continue
             k = e[0]
+            tw = e[-1]
             if k == "iteration":
                 t = e[1]
                 if e[7]:
                     for rid in e[7]:
-                        out.append({"type": "requeue", "t": t, "rid": rid})
-                ev = {"type": k, "t": t, "produced": e[2], "t_iter": e[3],
-                      "util": e[4], "fresh": e[5], "budget": e[6]}
+                        out.append({"type": "requeue", "t": t, "tw": tw,
+                                    "rid": rid})
+                ev = {"type": k, "t": t, "tw": tw, "produced": e[2],
+                      "t_iter": e[3], "util": e[4], "fresh": e[5],
+                      "budget": e[6]}
                 if e[8] is not None:
                     ev.update(e[8])
                 out.append(ev)
             elif k == "prefill_chunk":
-                out.append({"type": k, "t": e[1], "rid": e[2],
+                out.append({"type": k, "t": e[1], "tw": tw, "rid": e[2],
                             "chunk": e[3], "prefill_done": e[4]})
             else:                        # first_token
-                out.append({"type": k, "t": e[1], "rid": e[2]})
-        for rid in self._requeued:       # requeues after the last step
-            out.append({"type": "requeue", "t": self._now, "rid": rid})
+                out.append({"type": k, "t": e[1], "tw": tw, "rid": e[2]})
+        for rid in self._requeued:       # requeues after the last step,
+            out.append({"type": "requeue", "t": self._now,  # stamped no
+                        "tw": tw, "rid": rid})  # later than they happened
         self._mat, self._mat_key = out, key
         return out
 
@@ -289,7 +317,7 @@ class FlightRecorder(Observer):
         self.replica = idx
 
     def _ev(self, type_: str, t: float, **payload) -> dict:
-        ev = {"type": type_, "t": t}
+        ev = {"type": type_, "t": t, "tw": wall()}
         ev.update(payload)
         self._log.append(ev)
         return ev
@@ -337,7 +365,7 @@ class FlightRecorder(Observer):
 
     def on_prefill_chunk(self, req, chunk):
         self._log.append(("prefill_chunk", self._now, req.rid, chunk,
-                          req.prefill_done))
+                          req.prefill_done, wall()))
 
     def on_turn_release(self, req, now):
         self._now = now
@@ -358,9 +386,10 @@ class FlightRecorder(Observer):
     def on_iteration(self, now, *, t_iter, util, fresh, running, produced,
                      first):
         self._now = now
+        tw = wall()
         log = self._log
         for rid in first:
-            log.append(("first_token", now, rid))
+            log.append(("first_token", now, rid, tw))
         rq = self._requeued
         if rq:
             self._requeued = []
@@ -384,7 +413,7 @@ class FlightRecorder(Observer):
                     "active": sorted(sched.active_clients())}
         self._iter += 1
         log.append(("iteration", now, [r.rid for r in produced],
-                    t_iter, util, fresh, self._budget, rq or None, snap))
+                    t_iter, util, fresh, self._budget, rq or None, snap, tw))
 
     # -- views ------------------------------------------------------------
     def samples(self, full: bool = False) -> List[dict]:
@@ -425,6 +454,133 @@ def _scheduler_meta(core) -> dict:
     if isinstance(sched, RPM):
         meta["quota_per_min"] = sched.quota
     return meta
+
+
+# ---------------------------------------------------------------------------
+# step tracer: host spans and counters of the serving step
+# ---------------------------------------------------------------------------
+class _NoSpan:
+    """The span of a tracer that is off: enters and exits, does nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+def _profiler():
+    """``jax.profiler``, imported where a tracer that is on first needs
+    it: the simulator's paths never import jax."""
+    import jax.profiler
+    return jax.profiler
+
+
+class _Span:
+    """One host span: a profiler annotation plus its wall duration,
+    added to the open record under its name."""
+
+    __slots__ = ("spans", "name", "ann", "t")
+
+    def __init__(self, spans: dict, name: str, ann):
+        self.spans, self.name, self.ann = spans, name, ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self.t
+        self.ann.__exit__(*exc)
+        self.spans[self.name] = self.spans.get(self.name, 0) + dt
+        return False
+
+
+class _Step(_Span):
+    """``engine.step``: closes the record it times and opens the next."""
+
+    __slots__ = ("tracer",)
+
+    def __init__(self, tracer: "StepTracer", num: int):
+        tracer._open["step"] = num
+        super().__init__(tracer._open["span_ns"], "engine.step",
+                         _profiler().StepTraceAnnotation("engine.step",
+                                                         step_num=num))
+        self.tracer = tracer
+
+    def __enter__(self):
+        super().__enter__()
+        self.tracer._open["tw"] = self.t * 1e-9     # ``wall()`` at start
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self.tracer._close()
+        return False
+
+
+class StepTracer:
+    """Host spans and counters of the serving step (DESIGN.md §14).
+
+    ``ServingEngine`` opens ``span(name)`` around each part of an
+    iteration and ``step(n)`` around the whole.  While ``on``, a span
+    enters ``jax.profiler.TraceAnnotation(name)`` — so a running
+    profiler shows it on the host beside the device's ops, on the
+    profiler's clock — and adds its ``perf_counter_ns`` duration to the
+    open record; ``step(n)`` is a ``StepTraceAnnotation("engine.step",
+    step_num=n)`` that closes the record.  While off, ``span`` and
+    ``step`` return the shared ``NO_SPAN`` after one attribute check:
+    no clock read, no annotation, no record; the counter methods are
+    called only behind ``if tracer.on``.
+
+    One record per ``step`` (``drain()`` hands them over and forgets
+    them): ``step`` (the engine's iteration number), ``tw`` (``wall()``
+    at its start), ``span_ns`` ({span name: ns, summed}) and ``counts``
+    ({counter: value}).  Spans outside a step (``engine.submit``
+    between iterations) go to the record of the next step.
+    """
+
+    def __init__(self, on: bool = False):
+        self.on = on
+        self.records: List[dict] = []
+        self._new()
+
+    def _new(self):
+        self._open = {"span_ns": {}, "counts": {}}
+
+    def _close(self):
+        self.records.append(self._open)
+        self._new()
+
+    def span(self, name: str):
+        if not self.on:
+            return NO_SPAN
+        return _Span(self._open["span_ns"], name,
+                     _profiler().TraceAnnotation(name))
+
+    def step(self, num: int):
+        if not self.on:
+            return NO_SPAN
+        return _Step(self, num)
+
+    def count(self, **values):
+        """Add to the open record's counters (summed over a step)."""
+        c = self._open["counts"]
+        for k, v in values.items():
+            c[k] = c.get(k, 0) + v
+
+    def level(self, **values):
+        """Set counters that are a level, not a count (queue, pages)."""
+        self._open["counts"].update(values)
+
+    def drain(self) -> List[dict]:
+        """The closed records, oldest first; the tracer keeps none."""
+        out, self.records = self.records, []
+        return out
 
 
 # ---------------------------------------------------------------------------

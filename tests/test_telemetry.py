@@ -18,6 +18,8 @@
   admission control.
 - Telemetry-off parity: attaching a recorder must not perturb any
   modeled metric or scheduler counter.
+- Wall stamps: every event's ``tw`` never decreases along the log, and
+  replay reads only the modeled ``t``.
 """
 import json
 
@@ -246,6 +248,18 @@ def test_prediction_accuracy_surfaces_misprediction(cm):
     # ScaledOracle(0.2) under-predicts 5x -> |0.2x - x|/x = 0.8
     rel = max(v["rel_err"] for v in acc.values())
     assert rel == pytest.approx(0.8, abs=0.05)
+
+
+def test_wall_stamps_are_monotone_and_replay_ignores_them(cm):
+    _, _, sched, rec = _stress_run(cm, "equinox")
+    trace = rec.trace()
+    tws = [e["tw"] for e in trace["events"]]
+    assert all(isinstance(t, float) for t in tws)
+    assert tws == sorted(tws)
+    modeled = dict(trace, events=[{k: v for k, v in e.items() if k != "tw"}
+                                  for e in trace["events"]])
+    assert replay_counters(modeled) == replay_counters(trace) == \
+        scheduler_counters(sched)
 
 
 # -- telemetry-off parity -----------------------------------------------------

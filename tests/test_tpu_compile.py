@@ -109,7 +109,11 @@ def test_paged_decode_step_compiles_for_v5e(one_chip, quant, monkeypatch):
     compiled = _paged_decode_step.lower(
         params, *_ragged_shapes(one_chip), *_pool_shapes(one_chip, quant),
         CFG, PAGE).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the Pallas call's own name on its instruction, which a profile
+    # reader matches
+    assert "%paged_attention_splitk." in text
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
