@@ -43,8 +43,6 @@ BENCHES = [
                            "recorder-on vs off on a saturated trace"),
     ("sim_scale", "DESIGN.md §15: event-driven macro-stepping — "
                   "steady-decode speedup + provider-scale wall time"),
-    ("kernel_paged", "DESIGN.md §16: split-K + int8 paged-attention "
-                     "kernel parity and modeled long-context MFU"),
     ("cluster_scaling", "Beyond-paper: 1-8 replica fair cluster serving"),
     ("rpm_baseline", "Sec 1: static RPM quotas waste off-peak capacity"),
     ("roofline", "Deliverable (g): three-term roofline per arch x shape"),

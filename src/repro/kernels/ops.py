@@ -13,15 +13,8 @@ import functools
 import jax
 
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.paged_attention import (paged_attention_pallas,
-                                           paged_attention_splitk_pallas)
+from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
-
-# Split-K dispatch (DESIGN.md §16): block tables at least this many pages
-# wide route to the flash-decoding split-K kernel — below it the serial
-# page chain is short enough that the combine step would dominate.
-SPLIT_K_THRESHOLD_PAGES = 8
-DEFAULT_PAGES_PER_SPLIT = 4
 
 
 def _interpret() -> bool:
@@ -44,27 +37,11 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
 @jax.jit
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                     row_map=None, k_scale=None, v_scale=None):
-    """Serial below SPLIT_K_THRESHOLD_PAGES, split-K at or above it.  The
-    table width is static under jit, so the dispatch costs nothing."""
-    if block_tables.shape[1] >= SPLIT_K_THRESHOLD_PAGES:
-        return paged_attention_splitk_pallas(
-            q, k_pool, v_pool, block_tables, ctx_lens,
-            pages_per_split=DEFAULT_PAGES_PER_SPLIT, row_map=row_map,
-            k_scale=k_scale, v_scale=v_scale, interpret=_interpret())
+    """Each row walks its live pages only (DESIGN.md §16), at every
+    table width."""
     return paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens,
                                   row_map=row_map, k_scale=k_scale,
                                   v_scale=v_scale, interpret=_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("pages_per_split",))
-def paged_attention_splitk(q, k_pool, v_pool, block_tables, ctx_lens,
-                           row_map=None, k_scale=None, v_scale=None, *,
-                           pages_per_split=DEFAULT_PAGES_PER_SPLIT):
-    """Always split-K, regardless of table width."""
-    return paged_attention_splitk_pallas(
-        q, k_pool, v_pool, block_tables, ctx_lens,
-        pages_per_split=pages_per_split, row_map=row_map, k_scale=k_scale,
-        v_scale=v_scale, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -1,40 +1,42 @@
 """Pallas TPU paged attention — the serving engine's hot-spot (DESIGN.md
 §16).
 
-TPU adaptation of vLLM's PagedAttention: the per-request block table is
-*scalar-prefetched* so the kv-pool BlockSpec index maps can chase the
-indirection while the previous tile is still streaming HBM→VMEM.  Pool
-blocks are (page_size × head_dim) VMEM tiles of one kv head; flash-style
-(m, l, acc) statistics for the G query heads of a group live in VMEM
-scratch.
+TPU adaptation of vLLM's PagedAttention for a ragged mixed launch: one
+grid step per query row, which walks only the row's *live* pages —
+``ceil(ctx / page)`` of them — in blocks of ``pages_per_block`` pages and
+all kv heads at once.  Pages past the row's context are never copied and
+never computed, so the work of a row follows its context, not the table
+width.
 
-Three generalizations over the original one-page-at-a-time kernel:
-
-- **Ragged mixed launch** — ``row_map`` maps each query row to a row of a
+- **Copies.**  The pools stay in HBM (``memory_space=pl.ANY``).  One page
+  for all heads, ``pool[page_id]``, is one contiguous ``(Hkv, page, D)``
+  slab in the head-major layout, so a block is ``pages_per_block`` async
+  copies into a VMEM buffer of shape ``(2, pages_per_block, Hkv, page,
+  D)``.  The copies of the next block — the next row's first block after
+  a row's last — start before the current block is computed (double
+  buffering across rows too; the grid is sequential).
+- **Compute.**  Per kv head, the block's scores are ``(G, D) x (D,
+  pages_per_block * page)``, folded into float32 flash statistics
+  ``(m, l, acc)``.  Tokens past ``ctx`` are masked in the scores and in V,
+  so whatever a dead slot holds never reaches the output.
+- **Ragged launch.**  ``row_map`` maps each query row to a row of a
   *compact* block table, so one launch serves prefill-chunk rows (many
-  rows, one request, staggered ``ctx_lens``) and decode rows (one row per
-  request) together.  ``row_map=None`` keeps the legacy one-row-per-table
-  contract.
-- **Split-K flash decoding** (``paged_attention_splitk_pallas``) — long
-  contexts are partitioned across a split grid axis (``pages_per_split``
-  pages each); every split emits partial (acc, m, l) and a jnp combine
-  merges them.  The serial kernel chains *all* pages of a request through
-  one (m, l, acc) register state; split-K cuts that sequential dependency
-  to ``pages_per_split`` steps and lets the splits occupy parallel cores.
-- **int8 KV pages** — with ``k_scale``/``v_scale`` (per-(slot, head) bf16
-  scales matching the ``quantize_kv`` contract) the kernel dequantizes
-  int8 page tiles in-VMEM, halving the KV HBM stream.
+  rows, one request, staggered ``ctx_lens``) and decode rows together.
+  ``row_map=None`` maps row b to table row b.
+- **int8 KV pages.**  With ``k_scale``/``v_scale`` (per-(slot, head)
+  bf16 scales, the ``quantize_kv`` contract) the scales are copied per
+  page beside K and V and the pages are dequantized in VMEM.
 
 Inputs:
-    q            (B, Hq, D)        one token per query row
-    k_pool/v_pool(P, Hkv, page, D) global paged KV pools (fp or int8),
-                                   head-major: Mosaic needs a block's last
-                                   two dims to be (page, D) tiles, so the
-                                   squeezed head axis cannot sit between
-    block_tables (T, n_pages)      int32 pool-page ids per table row
-    ctx_lens     (B,)              int32 valid context length per query row
-    row_map      (B,) or None      int32 table row per query row
-    k/v_scale    (P, Hkv, page)    bf16 dequant scales (int8 pools only)
+    q            (B, Hq, D)         one token per query row
+    k_pool/v_pool(P, Hkv, page, Dp) global paged KV pools (fp or int8);
+                                    Dp >= D, zeros past D (the engine's
+                                    pools are ``lane_dense``)
+    block_tables (T, n_pages)       int32 pool-page ids per table row
+    ctx_lens     (B,)               int32 valid context length per query row
+    row_map      (B,) or None       int32 table row per query row
+    k/v_scale    (P, Hkv, S)        bf16 dequant scales, slot i in lane i,
+                                    S >= page (int8 pools only)
 
 Fully masked rows (``ctx_lens[b] == 0``) return exact zeros: masked
 scores contribute ``p = 0`` (an explicit mask multiply — NEG_INF is
@@ -51,10 +53,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
+# VMEM for one of the four double-buffered K/V block slots.  VMEM tiles
+# are (sublanes, 128 lanes), so a page costs its padded footprint.
+BLOCK_VMEM_BYTES = 2 * 2**20
+
+
+def lane_dense(n: int) -> int:
+    """``n`` rounded up to whole 128-lane rows.  Mosaic copies only whole
+    128-lane tiles out of HBM, so the pools keep their minor axis
+    (head_dim; the page's slots in the scale pools) padded to this
+    width."""
+    return -(-n // LANES) * LANES
 
 
 def _validate(q, k_pool, block_tables, row_map, k_scale, v_scale):
-    B, Hq, _ = q.shape
+    B, Hq, D = q.shape
     Hkv = k_pool.shape[1]
     if Hq % Hkv != 0:
         raise ValueError(
@@ -73,269 +87,224 @@ def _validate(q, k_pool, block_tables, row_map, k_scale, v_scale):
     if (k_scale is None) != (v_scale is None):
         raise ValueError("paged attention: k_scale and v_scale must be "
                          "passed together (int8 pools) or not at all")
+    if k_pool.shape[-1] < D:
+        raise ValueError(f"paged attention: pools hold {k_pool.shape[-1]} "
+                         f"lanes per token, q has head_dim {D}")
 
 
-def _flash_step(q_ref, k_ref, v_ref, ks_ref, vs_ref, ctx, page_start,
-                acc_ref, m_ref, l_ref, *, sm_scale):
-    """One page's online-softmax update of the (m, l, acc) scratch."""
-    q = q_ref[...].astype(jnp.float32)            # (G, D)
-    k = k_ref[...].astype(jnp.float32)            # (page, D)
-    v = v_ref[...].astype(jnp.float32)            # (page, Dv)
-    if ks_ref is not None:                        # int8 pages: dequant in VMEM
-        k = k * ks_ref[...].astype(jnp.float32)   # (page, 1) scales
-        v = v * vs_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    tokpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = tokpos < ctx
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # explicit mask multiply: when EVERY score is masked m_new == NEG_INF
-    # (finite), so exp(s - m_new) alone would be exp(0) == 1 and a ctx=0
-    # row would average garbage V instead of returning zeros
-    p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+def _tile_bytes(rows, cols, dtype):
+    """VMEM bytes of a (rows, cols) array: whole tiles of (8 * 32-bit
+    packing) sublanes by 128 lanes."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // item)
+    return -(-rows // sub) * sub * lane_dense(cols) * item
 
 
-def _paged_kernel(*refs, page, n_pages, sm_scale, quant, stats):
-    tables_ref, rows_ref, ctx_ref = refs[:3]
-    del tables_ref, rows_ref                      # consumed by index maps
-    q_ref, k_ref, v_ref = refs[3:6]
-    i = 6
-    ks_ref = vs_ref = None
+def pages_per_block_for(k_pool, k_scale, width):
+    """Pages a block holds: as many as fit ``BLOCK_VMEM_BYTES`` of one
+    K slot (the page's ``(Hkv, page, D)`` tiles, and its scale tiles for
+    int8 pools), as a power of two, at most the table width."""
+    _, Hkv, page, D = k_pool.shape
+    per_page = Hkv * _tile_bytes(page, D, k_pool.dtype)
+    if k_scale is not None:
+        per_page += _tile_bytes(Hkv, k_scale.shape[-1], k_scale.dtype)
+    n = max(1, min(BLOCK_VMEM_BYTES // per_page, width))
+    return 1 << (n.bit_length() - 1)
+
+
+def _scale_columns(s_ref, page):
+    """One block's int8 scales ``(ppb, Hkv, lanes)`` (slot i of page p in
+    lane i) as ``(T, ppb * Hkv)`` float32: entry (t, p * Hkv + h) is
+    head h's scale of slot ``t % page`` in page p.  A one-hot matmul —
+    exact, every sum has one term — moves the slots from lanes to
+    sublanes, where a token's scale has to sit to scale its row."""
+    ppb, Hkv, n = s_ref.shape
+    T = ppb * page
+    a = s_ref[...].astype(jnp.float32).reshape(ppb * Hkv, n)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (T, n), 0) % page
+    onehot = slot == jax.lax.broadcasted_iota(jnp.int32, (T, n), 1)
+    return jax.lax.dot_general(
+        onehot.astype(jnp.bfloat16), a.astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _head_column(cols, h, Hkv, page):
+    """Head h's per-token scales ``(T, 1)`` out of ``_scale_columns``."""
+    T, n = cols.shape
+    want = (jax.lax.broadcasted_iota(jnp.int32, (T, n), 0) // page) * Hkv + h
+    pick = jax.lax.broadcasted_iota(jnp.int32, (T, n), 1) == want
+    return jnp.sum(jnp.where(pick, cols, 0.0), axis=1, keepdims=True)
+
+
+def _live_kernel(tables_ref, rows_ref, ctx_ref, q_ref, k_hbm, v_hbm,
+                 *refs, page, ppb, width, sm_scale, quant, cdt):
     if quant:
-        ks_ref, vs_ref = refs[6:8]
-        i = 8
-    o_ref = refs[i]
-    i += 1
-    if stats:
-        mo_ref, lo_ref = refs[i:i + 2]
-        i += 2
-    acc_ref, m_ref, l_ref = refs[i:i + 3]
-
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref,
+         m_ref, l_ref, acc_ref) = refs
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                 (vs_hbm, vs_buf))
+    else:
+        (o_ref, k_buf, v_buf, sem, slot_ref, m_ref, l_ref, acc_ref) = refs
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    n_rows = pl.num_programs(0)
+    _, _, Hkv, _, D = k_buf.shape
+    T = ppb * page                                  # tokens per block
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live_pages(r):
+        return jnp.minimum(pl.cdiv(ctx_ref[r], page), width)
 
-    _flash_step(q_ref, k_ref, v_ref, ks_ref, vs_ref, ctx_ref[b], j * page,
-                acc_ref, m_ref, l_ref, sm_scale=sm_scale)
+    def n_blocks(r):                 # >= 1: a ctx=0 row still writes zeros
+        return jnp.maximum(pl.cdiv(live_pages(r), ppb), 1)
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
-        if stats:
-            mo_ref[...] = m_ref[...]
-            lo_ref[...] = l_ref[...]
+    def block_copies(r, blk, slot, verb):
+        """Start (or wait for) the copies of row r's block blk into
+        ``slot``: one per live page, K, V (and their scales) each."""
+        first = blk * ppb
+        n = jnp.clip(live_pages(r) - first, 0, ppb)
+
+        def one(j, carry):
+            pid = tables_ref[rows_ref[r], first + j]
+            for src, buf in pairs:
+                cp = pltpu.make_async_copy(src.at[pid], buf.at[slot, j],
+                                           sem.at[slot])
+                getattr(cp, verb)()
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        block_copies(0, 0, 0, "start")
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    ctx = jnp.minimum(ctx_ref[b], width * page)   # no token past the table
+    nb = n_blocks(b)
+    slot0 = slot_ref[0]
+
+    def block(i, carry):
+        slot = (slot0 + i) % 2
+
+        @pl.when(i + 1 < nb)
+        def _next_block():
+            block_copies(b, i + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_and(i + 1 == nb, b + 1 < n_rows))
+        def _next_row():
+            block_copies(b + 1, 0, 1 - slot, "start")
+
+        block_copies(b, i, slot, "wait")
+        tok0 = i * T
+        mask = (tok0 + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)) < ctx
+        vmask = (tok0 + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)) < ctx
+        if quant:
+            k_cols = _scale_columns(ks_buf.at[slot], page)
+            v_cols = _scale_columns(vs_buf.at[slot], page)
+
+        def head(h, c):
+            q = q_ref[h].astype(cdt)                      # (G, D)
+            if quant:                                     # dequant in VMEM
+                k = (k_buf[slot, :, h].astype(jnp.float32).reshape(T, D)
+                     * _head_column(k_cols, h, Hkv, page))
+                v = (v_buf[slot, :, h].astype(jnp.float32).reshape(T, D)
+                     * _head_column(v_cols, h, Hkv, page))
+            else:
+                k = k_buf[slot, :, h].reshape(T, D)
+                v = v_buf[slot, :, h].reshape(T, D)
+            # a dead slot may hold anything (NaN included): 0 * NaN is
+            # NaN, so V is zeroed, not just weighted by p = 0
+            v = jnp.where(vmask, v, 0).astype(cdt)
+            s = jax.lax.dot_general(
+                q, k.astype(cdt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(mask, s, NEG_INF)               # (G, T)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # explicit mask multiply: when EVERY score is masked m_new ==
+            # NEG_INF (finite), so exp(s - m_new) alone would be 1
+            p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(cdt), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+            return c
+
+        jax.lax.fori_loop(0, Hkv, head, 0)
+        return carry
+
+    jax.lax.fori_loop(0, nb, block, 0)
+    slot_ref[0] = (slot0 + nb) % 2
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _splitk_kernel(*refs, page, pages_per_split, sm_scale, quant):
-    tables_ref, rows_ref, ctx_ref = refs[:3]
-    del tables_ref, rows_ref                      # consumed by index maps
-    q_ref, k_ref, v_ref = refs[3:6]
-    i = 6
-    ks_ref = vs_ref = None
-    if quant:
-        ks_ref, vs_ref = refs[6:8]
-        i = 8
-    acc_out, m_out, l_out = refs[i:i + 3]
-    acc_ref, m_ref, l_ref = refs[i + 3:i + 6]
-
-    b = pl.program_id(0)
-    s_id = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    page_global = s_id * pages_per_split + j
-    _flash_step(q_ref, k_ref, v_ref, ks_ref, vs_ref, ctx_ref[b],
-                page_global * page, acc_ref, m_ref, l_ref,
-                sm_scale=sm_scale)
-
-    @pl.when(j == pages_per_split - 1)
-    def _flush():                                 # partial stats, no division
-        acc_out[...] = acc_ref[...]
-        m_out[...] = m_ref[...]
-        l_out[...] = l_ref[...]
-
-
-def _prep(q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
-          v_scale):
-    """Shared shape plumbing of both launch variants."""
+def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens, *,
+                           row_map=None, k_scale=None, v_scale=None,
+                           pages_per_block=None, interpret=False):
+    """Returns (B, Hq, D).  The pools may carry more lanes per token than
+    q's head_dim D (``lane_dense``; zeros there), and int8 scale pools
+    more lanes than a page has slots.  ``pages_per_block=None`` derives
+    the block from the pools' shapes and dtypes
+    (``pages_per_block_for``); tests force small blocks.  The matmuls
+    take q's and the pool's common dtype (bf16 for bf16 or int8 pools
+    under bf16 activations), accumulating in float32."""
     _validate(q, k_pool, block_tables, row_map, k_scale, v_scale)
     B, Hq, D = q.shape
-    Hkv = k_pool.shape[1]
+    _, Hkv, page, Dp = k_pool.shape
     G = Hq // Hkv
+    width = block_tables.shape[1]
+    quant = k_scale is not None
+    if pages_per_block is None:
+        pages_per_block = pages_per_block_for(k_pool, k_scale, width)
+    if pages_per_block <= 0:
+        raise ValueError(f"pages_per_block must be >= 1, got "
+                         f"{pages_per_block}")
     if row_map is None:
         row_map = jnp.arange(B, dtype=jnp.int32)
     scalars = (jnp.asarray(block_tables, jnp.int32),
                jnp.asarray(row_map, jnp.int32),
                jnp.asarray(ctx_lens, jnp.int32))
-    inputs = [q.reshape(B, Hkv, G, D), k_pool, v_pool]
-    if k_scale is not None:
-        inputs += [k_scale[..., None], v_scale[..., None]]
-    return B, Hq, D, Hkv, G, scalars, inputs
-
-
-def paged_attention_pallas(q, k_pool, v_pool, block_tables, ctx_lens, *,
-                           row_map=None, k_scale=None, v_scale=None,
-                           return_stats=False, interpret=False):
-    """Serial page-innermost variant.  Returns (B, Hq, Dv); with
-    ``return_stats`` also the per-row softmax statistics (m, l), each
-    (B, Hq) float32 — the cross-variant comparison hook (m is *bitwise*
-    comparable with the split-K combine: max is exact)."""
-    B, Hq, D, Hkv, G, scalars, inputs = _prep(
-        q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
-        v_scale)
-    page = k_pool.shape[2]
-    Dv = v_pool.shape[-1]
-    n_pages = block_tables.shape[1]
-    quant = k_scale is not None
-
-    kernel = functools.partial(_paged_kernel, page=page, n_pages=n_pages,
-                               sm_scale=D ** -0.5, quant=quant,
-                               stats=return_stats)
-
-    def q_index(b, h, j, tables, rows, ctx):
-        return (b, h, 0, 0)
-
-    def kv_index(b, h, j, tables, rows, ctx):
-        return (tables[rows[b], j], h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((None, None, G, D), q_index),
-        pl.BlockSpec((None, None, page, D), kv_index),
-        pl.BlockSpec((None, None, page, Dv), kv_index),
-    ]
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, Dp - D))).reshape(B, Hkv, G, Dp)
+    inputs = [q, k_pool, v_pool]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    row_spec = pl.BlockSpec((None, Hkv, G, Dp), lambda b, *_: (b, 0, 0, 0))
+    in_specs = [row_spec, any_spec, any_spec]
+    scratch = [pltpu.VMEM((2, pages_per_block, Hkv, page, Dp),
+                          k_pool.dtype)] * 2
     if quant:
-        in_specs += [pl.BlockSpec((None, None, page, 1), kv_index)] * 2
-    o_spec = pl.BlockSpec((None, None, G, Dv), q_index)
-    o_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype)
-    if return_stats:
-        s_spec = pl.BlockSpec((None, None, G, 1), q_index)
-        s_shape = jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32)
-        out_specs, out_shape = (o_spec, s_spec, s_spec), \
-            (o_shape, s_shape, s_shape)
-    else:
-        out_specs, out_shape = o_spec, o_shape
+        inputs += [k_scale, v_scale]
+        in_specs += [any_spec, any_spec]
+        scratch += [pltpu.VMEM((2, pages_per_block) + k_scale.shape[1:],
+                               k_scale.dtype)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),         # the next block's slot
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, Dp), jnp.float32)]
+    kernel = functools.partial(
+        _live_kernel, page=page, ppb=pages_per_block, width=width,
+        sm_scale=D ** -0.5, quant=quant,
+        cdt=jnp.promote_types(q.dtype, k_pool.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,              # block_tables, row_map, ctx_lens
-        grid=(B, Hkv, n_pages),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((G, Dv), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
+        out_specs=row_spec,
+        scratch_shapes=scratch,
     )
     # named: the kernel's instruction in a profile is
-    # ``%paged_attention_serial.N``, whatever jit wraps it
-    outs = pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret,
-                          name="paged_attention_serial")(*scalars, *inputs)
-    if return_stats:
-        out, m, l = outs
-        return (out.reshape(B, Hq, Dv), m.reshape(B, Hq),
-                l.reshape(B, Hq))
-    return outs.reshape(B, Hq, Dv)
-
-
-def paged_attention_splitk_pallas(q, k_pool, v_pool, block_tables,
-                                  ctx_lens, *, pages_per_split=4,
-                                  row_map=None, k_scale=None, v_scale=None,
-                                  return_stats=False, interpret=False):
-    """Flash-decoding split-K variant (DESIGN.md §16): the page axis is
-    partitioned into ``ceil(n_pages / pages_per_split)`` splits; each
-    split accumulates private (m, l, acc) partials over its pages and the
-    final combine rescales by ``exp(m_s - max_s m_s)`` outside the
-    kernel.  Identical math to the serial kernel up to summation order
-    (m is bitwise identical — max is exact)."""
-    if pages_per_split <= 0:
-        raise ValueError(f"pages_per_split must be >= 1, got "
-                         f"{pages_per_split}")
-    B, Hq, D, Hkv, G, scalars, inputs = _prep(
-        q, k_pool, v_pool, block_tables, ctx_lens, row_map, k_scale,
-        v_scale)
-    page = k_pool.shape[2]
-    Dv = v_pool.shape[-1]
-    n_pages = block_tables.shape[1]
-    quant = k_scale is not None
-    n_splits = -(-n_pages // pages_per_split)
-    padded = n_splits * pages_per_split
-    if padded != n_pages:                  # pad with page 0 — masked by ctx
-        tables = jnp.pad(scalars[0], ((0, 0), (0, padded - n_pages)))
-        scalars = (tables,) + scalars[1:]
-
-    kernel = functools.partial(_splitk_kernel, page=page,
-                               pages_per_split=pages_per_split,
-                               sm_scale=D ** -0.5, quant=quant)
-
-    def q_index(b, h, s, j, tables, rows, ctx):
-        return (b, h, 0, 0)
-
-    def kv_index(b, h, s, j, tables, rows, ctx):
-        return (tables[rows[b], s * pages_per_split + j], h, 0, 0)
-
-    def part_index(b, h, s, j, tables, rows, ctx):
-        return (b, h, s, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((None, None, G, D), q_index),
-        pl.BlockSpec((None, None, page, D), kv_index),
-        pl.BlockSpec((None, None, page, Dv), kv_index),
-    ]
-    if quant:
-        in_specs += [pl.BlockSpec((None, None, page, 1), kv_index)] * 2
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, n_splits, pages_per_split),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((None, None, None, G, Dv), part_index),
-            pl.BlockSpec((None, None, None, G, 1), part_index),
-            pl.BlockSpec((None, None, None, G, 1), part_index),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((G, Dv), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
-    )
-    acc_p, m_p, l_p = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, G, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, n_splits, G, 1), jnp.float32),
-        ),
-        interpret=interpret,
-        name="paged_attention_splitk",   # ``%paged_attention_splitk.N``
+    # ``%paged_attention_live.N``, whatever jit wraps it
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dp), q.dtype),
+        # rows run in order: each prefetches the next row's first block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_attention_live",
     )(*scalars, *inputs)
-    # combine: m = max_s m_s (exact); partials rescale by exp(m_s - m).
-    # Splits fully beyond ctx carry (m=NEG_INF, l=0, acc=0) and vanish;
-    # a fully masked row keeps l=0 and the clamp returns zeros.
-    m = jnp.max(m_p, axis=2, keepdims=True)          # (B, Hkv, 1, G, 1)
-    alpha = jnp.exp(m_p - m)
-    l = jnp.sum(l_p * alpha, axis=2)                 # (B, Hkv, G, 1)
-    acc = jnp.sum(acc_p * alpha, axis=2)             # (B, Hkv, G, Dv)
-    out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
-    if return_stats:
-        return (out.reshape(B, Hq, Dv), m[:, :, 0].reshape(B, Hq),
-                l.reshape(B, Hq))
-    return out.reshape(B, Hq, Dv)
+    return out.reshape(B, Hq, Dp)[..., :D]

@@ -63,7 +63,8 @@ from repro.models.attention import apply_rope, quantize_kv
 from repro.models.moe import moe_ffn
 from repro.serving.batch_core import BatchConfig, BatchCore
 from repro.serving.costmodel import CostModel
-from repro.serving.kv_cache import PagePool, make_pools, scatter_prefill
+from repro.serving.kv_cache import (PagePool, make_pools, pad_heads,
+                                    scatter_prefill)
 from repro.serving.telemetry import StepTracer
 
 def _next_pow2(n: int) -> int:
@@ -370,7 +371,7 @@ class ServingEngine:
             return {}, {}
         tr = self.tracer
         with tr.span("step.pack"):
-            step_args, last_row, n_chunk_rows, shape = self._pack_mixed(
+            step_args, last_row, n_chunk_rows, launch = self._pack_mixed(
                 plan, decoding)
         with tr.span("step.dispatch"):
             if self.kv_quant:
@@ -392,19 +393,26 @@ class ServingEngine:
             rows = {r.rid: logits[n_chunk_rows + i]
                     for i, r in enumerate(decoding)}
         if tr.on:
-            n_rows, n_pad = shape
+            n_rows, pos, width = launch
+            n_pad = len(pos)
             # the step reads a chunk's row only where it ends the prompt
             used = sum(req.prefill_done >= req.prompt_len for req, _ in plan)
+            page = self.pool.page_size
+            # pages the kernel copies: each launched row's ceil(ctx/page),
+            # ctx = pos + 1; against the full table width
             tr.count(rows_real=n_rows, rows_launched=n_pad,
                      logits_rows_used=used + len(decoding),
-                     logits_rows_fetched=n_pad)
+                     logits_rows_fetched=n_pad,
+                     attn_pages_live=sum(p // page + 1 for p in pos),
+                     attn_pages_table=n_pad * width)
         return first_rows, rows
 
     def _pack_mixed(self, plan, decoding: List[Request]):
         """The fused step's inputs on the device: pages ensured, then
         tokens, positions, block table and row map, padded to their
         buckets.  Returns (step args, {rid: its chunk's last row},
-        number of chunk rows, (real rows, launched rows))."""
+        number of chunk rows, (real rows, each launched row's position,
+        table width))."""
         tokens: List[int] = []
         ctx: List[int] = []
         rmap: List[int] = []
@@ -445,7 +453,7 @@ class ServingEngine:
         step_args = (self.params, *self._place((
             np.asarray(tokens, np.int32), np.asarray(ctx, np.int32), bt,
             np.asarray(rmap, np.int32))))
-        return step_args, last_row, n_chunk_rows, (n_rows, n_pad)
+        return step_args, last_row, n_chunk_rows, (n_rows, ctx, width)
 
     def _install_prefill(self, req: Request, row):
         """Prompt fully prefilled: make the request decodable.  For the
@@ -725,8 +733,9 @@ def _paged_decode_step(params, tokens, ctx_lens, block_tables, row_map,
             v, v_s = quantize_kv(v)
             ks = ks.at[page_idx, :, slot_idx].set(k_s)
             vs = vs.at[page_idx, :, slot_idx].set(v_s)
-        kp = kp.at[page_idx, :, slot_idx].set(k)          # head-major pages
-        vp = vp.at[page_idx, :, slot_idx].set(v)
+        # head-major pages, head_dim padded to the pools' lanes
+        kp = kp.at[page_idx, :, slot_idx].set(pad_heads(k, kp))
+        vp = vp.at[page_idx, :, slot_idx].set(pad_heads(v, vp))
         out = paged_attention(q, kp, vp, block_tables, pos + 1,
                               row_map=row_map, k_scale=ks, v_scale=vs)
         y = jnp.einsum("bhk,hkd->bd", out, lp["attn"]["wo"])[:, None]

@@ -1,11 +1,12 @@
 """Paged KV-cache block manager (host side) + pool tensors (device side).
 
 vLLM-style indirection adapted to TPU tiles (DESIGN.md §3): the pools are
-head-major (n_pages, n_kv_heads, page_size, head_dim) arrays per layer, so
-one kv head's page is a (page_size, head_dim) tile; requests own
+head-major (n_pages, n_kv_heads, page_size, head_dim) arrays per layer
+(head_dim padded to whole 128-lane rows), so one page for all kv heads
+is one contiguous slab the kernel copies at once; requests own
 lists of page ids; block tables are dense int32 matrices handed to the
-Pallas paged-attention kernel (0-padded — padding pages are masked by
-``ctx_lens`` inside the kernel).
+Pallas paged-attention kernel (0-padded — the kernel reads only each
+row's first ``ceil(ctx / page)`` pages).
 
 Pages are **refcounted** so the shared-prefix radix cache (DESIGN.md §9,
 ``repro.serving.prefix_cache``) can point several requests' block tables
@@ -22,6 +23,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Set
 
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.paged_attention import lane_dense
 
 
 class PagePool:
@@ -197,19 +200,30 @@ class PagePool:
 
 def make_pools(n_layers: int, n_pages: int, page_size: int, n_kv_heads: int,
                head_dim: int, dtype=jnp.float32, quantized: bool = False):
-    """Stacked per-layer K/V pools: (L, n_pages, Hkv, page, D).
+    """Stacked per-layer K/V pools: (L, n_pages, Hkv, page, lane_dense(D)).
+
+    The kernel copies pages out of HBM, and Mosaic copies only whole
+    128-lane tiles, so head_dim is padded to them (``lane_dense``); the
+    padding stays zero (``pad_heads``).
 
     ``quantized=True`` (DESIGN.md §16) returns int8 payload pools plus
-    per-(slot, head) bf16 scale pools (L, n_pages, Hkv, page) — the
-    ``quantize_kv`` contract (scales are the payload shape minus the
-    trailing head_dim axis).  Zero-initialized scales are safe: an unwritten
-    slot dequantizes to exact zeros."""
-    shape = (n_layers, n_pages, n_kv_heads, page_size, head_dim)
+    per-(slot, head) bf16 scale pools (L, n_pages, Hkv, lane_dense(page)),
+    slot i's scale in lane i — the ``quantize_kv`` contract.
+    Zero-initialized scales are safe: an unwritten slot dequantizes to
+    exact zeros."""
+    shape = (n_layers, n_pages, n_kv_heads, page_size, lane_dense(head_dim))
     if quantized:
+        scales = (n_layers, n_pages, n_kv_heads, lane_dense(page_size))
         return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(shape[:-1], jnp.bfloat16),
-                jnp.zeros(shape[:-1], jnp.bfloat16))
+                jnp.zeros(scales, jnp.bfloat16),
+                jnp.zeros(scales, jnp.bfloat16))
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def pad_heads(x, pool):
+    """K or V rows (..., head_dim) zero-padded to the pool's lanes."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                   + [(0, pool.shape[-1] - x.shape[-1])])
 
 
 def scatter_prefill(pool, layer_caches, pages: List[int], page_size: int,
@@ -226,7 +240,7 @@ def scatter_prefill(pool, layer_caches, pages: List[int], page_size: int,
         if lo >= S:
             break
         hi = min(lo + page_size, S)
-        chunk = layer_caches[:, lo:hi]
+        chunk = pad_heads(layer_caches[:, lo:hi], pool)
         if hi - lo < page_size:
             chunk = jnp.pad(chunk, ((0, 0), (0, page_size - (hi - lo)),
                                     (0, 0), (0, 0)))

@@ -299,3 +299,49 @@ def test_recorder_stamps_share_the_tracer_clock(params):
             assert arrived[ev["rid"]] <= ev["tw"]
             assert any(a <= ev["tw"] <= b for a, b in steps)
     assert sorted(arrived) == list(range(6))
+
+
+def _known_plan(eng):
+    """One launch by hand, pages of 16: a 20-token prompt chunk at
+    positions 16..35, decode rows at positions 50 and 15, and padding to
+    32 rows.  Returns (plan, decoding)."""
+    rng = np.random.default_rng(3)
+    chunk = Request(rid=0, client="a", arrival=0.0, prompt_len=40,
+                    output_len=4, keywords=("chat",),
+                    prompt_tokens=rng.integers(0, CFG.vocab_size, 40,
+                                               dtype=np.int32))
+    chunk.prefill_done = 36
+    decoding = []
+    for rid, pos in ((1, 50), (2, 15)):
+        r = Request(rid=rid, client="b", arrival=0.0, prompt_len=pos,
+                    output_len=8, keywords=("chat",))
+        r._pos, r._next_token = pos, 1
+        eng.pool.ensure(rid, pos)
+        decoding.append(r)
+    return [(chunk, 20)], decoding
+
+
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_attn_page_counters_match_hand_count(params, on, monkeypatch):
+    """``attn_pages_live``: each launched row's ceil(ctx / page) with ctx
+    = position + 1 — what the kernel copies; ``attn_pages_table``: rows
+    launched x table width — what a full-width walk reads.  A tracer that
+    is off counts nothing."""
+    tracer = StepTracer(on=on)
+    if not on:
+        def refuse(**kw):
+            raise AssertionError("a tracer that is off was counted into")
+        monkeypatch.setattr(tracer, "count", refuse)
+    eng = _engine(params, "prefix", tracer)          # max_len 96: width 8
+    eng._run_mixed_paged(*_known_plan(eng))
+    if not on:
+        assert tracer.records == []
+        assert tracer._open == {"span_ns": {}, "counts": {}}
+        return
+    c = tracer._open["counts"]
+    chunk = 16 * 2 + 4 * 3          # positions 16..31: 2 pages; 32..35: 3
+    decode = 4 + 1                  # positions 50 (51 tokens) and 15 (16)
+    padding = (32 - 22) * 1         # token 0 at position 0: one page each
+    assert c["rows_real"] == 22 and c["rows_launched"] == 32
+    assert c["attn_pages_live"] == chunk + decode + padding
+    assert c["attn_pages_table"] == 32 * 8

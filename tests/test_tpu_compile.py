@@ -3,9 +3,10 @@
 Interpret mode checks a kernel's indexing but not Mosaic's tiling rules,
 and a CPU run cannot tell whether a whole step fits the chip's HBM.  These
 tests hand shapes (never arrays) on a described ``v5e:2x2`` device to the
-TPU compiler, at Granite-3.0-2B's published widths, and check that every
-paged-attention variant and the fused serving step lower to a Mosaic
-kernel (``tpu_custom_call``).  Nothing runs, so nothing here is a timing.
+TPU compiler, and check that the paged-attention kernel — at the widths of
+both benchmark models, with bf16 and int8 pools — and the fused serving
+step at Granite-3.0-2B's published widths lower to a Mosaic kernel
+(``tpu_custom_call``).  Nothing runs, so nothing here is a timing.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and only the worker that runs this
@@ -17,14 +18,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.paged_attention import (paged_attention_pallas,
-                                           paged_attention_splitk_pallas)
+from repro.kernels.paged_attention import paged_attention_pallas
 
 pytestmark = pytest.mark.kernels
 
 CFG = get_config("granite-3-2b")
 PAGE = 16
 ROWS = 512                       # one iteration's ragged query rows
+# (kv heads, query heads per kv head, head_dim) of the benchmark's models
+WIDTHS = {"granite-3-2b": (8, 4, 64), "deepseek-llm-7b": (32, 1, 128)}
 TABLE = (32, 2048 // PAGE)       # table rows x pages of max_len=2048
 POOL_PAGES = 32768 // PAGE + 1   # a 32k-token KV budget + the scratch page
 HBM_BYTES = 16 * 2**30           # one v5e chip
@@ -56,11 +58,12 @@ def _on(sharding, tree):
         tree)
 
 
-def _pool_shapes(sharding, quant):
+def _pool_shapes(sharding, quant, n_layers=CFG.n_layers,
+                 n_kv_heads=CFG.n_kv_heads, head_dim=CFG.resolved_head_dim()):
     from repro.serving.kv_cache import make_pools
     pools = jax.eval_shape(lambda: make_pools(
-        CFG.n_layers, POOL_PAGES, PAGE, CFG.n_kv_heads,
-        CFG.resolved_head_dim(), jnp.bfloat16, quantized=quant))
+        n_layers, POOL_PAGES, PAGE, n_kv_heads, head_dim, jnp.bfloat16,
+        quantized=quant))
     pools = _on(sharding, pools)
     return pools if quant else (*pools, None, None)
 
@@ -74,26 +77,28 @@ def _ragged_shapes(sharding):
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("kernel", [paged_attention_pallas,
-                                    paged_attention_splitk_pallas],
-                         ids=["serial", "splitk"])
-def test_paged_kernel_compiles_for_v5e(one_chip, kernel, quant):
-    H, Hkv, D = CFG.n_heads, CFG.n_kv_heads, CFG.resolved_head_dim()
-    q = jax.ShapeDtypeStruct((ROWS, H, D), jnp.bfloat16, sharding=one_chip)
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+def test_paged_kernel_compiles_for_v5e(one_chip, model, quant):
+    Hkv, G, D = WIDTHS[model]
+    q = jax.ShapeDtypeStruct((ROWS, Hkv * G, D), jnp.bfloat16,
+                             sharding=one_chip)
     # one layer's pools
     kp, vp, ks, vs = (None if a is None else
                       jax.ShapeDtypeStruct(a.shape[1:], a.dtype,
                                            sharding=one_chip)
-                      for a in _pool_shapes(one_chip, quant))
+                      for a in _pool_shapes(one_chip, quant, 1, Hkv, D))
     _, ctx, tables, rows = _ragged_shapes(one_chip)
 
     def fn(q, kp, vp, tables, ctx, rows, ks, vs):
-        return kernel(q, kp, vp, tables, ctx, row_map=rows, k_scale=ks,
-                      v_scale=vs, interpret=False)
+        return paged_attention_pallas(q, kp, vp, tables, ctx, row_map=rows,
+                                      k_scale=ks, v_scale=vs,
+                                      interpret=False)
 
     compiled = jax.jit(fn).lower(q, kp, vp, tables, ctx, rows, ks,
                                  vs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_attention_live." in text
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -113,7 +118,7 @@ def test_paged_decode_step_compiles_for_v5e(one_chip, quant, monkeypatch):
     assert "tpu_custom_call" in text
     # the Pallas call's own name on its instruction, which a profile
     # reader matches
-    assert "%paged_attention_splitk." in text
+    assert "%paged_attention_live." in text
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
