@@ -20,16 +20,16 @@ import jax.numpy as jnp  # noqa: E402
 def main(name: str):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from config import load_config, program_config
+    from config import family, load_config
     from harness import buckets, next_pow2
     from repro.kernels import ops
     from repro.serving.engine import _paged_decode_step
     from repro.serving.kv_cache import make_pools
-    from weights import program_params
 
     ops._interpret = lambda: False        # tracing sees the CPU backend
     c = load_config(name)
-    cfg, e = program_config(c), c["engine"]
+    fam = family(c)
+    cfg, e = fam.program_config(c), c["engine"]
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
 
@@ -37,7 +37,7 @@ def main(name: str):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=chip), tree)
 
-    params = on(jax.eval_shape(lambda: program_params(c, 0)))
+    params = on(jax.eval_shape(lambda: fam.program_params(c, 0)))
     rows, tabs = max(buckets(e))
     width = next_pow2(-(-e["max_len"] // e["page_size"]))
     i32 = jnp.int32

@@ -14,13 +14,11 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from config import BENCH, ROOT, cell_metrics, load_config
+from config import BENCH, ROOT, cell_metrics, family, load_config
 from harness import (SPANS, CompileCount, Session, end_to_end, free, serve,
                      sample, check)
 from stats import percentile
 from traffic import generate, load_mix
-from weights import program_params
-from work import shape_of
 
 TRACE_FROM = 0.6     # the traced slice: from this share of the window
 #                      to its end
@@ -57,7 +55,7 @@ def per_layer(workload, c, peaks, out, e2e, trace_dir, device) -> tuple:
     t0, t1 = min(starts), max(ends)
     a, b = out.trace_span
     run = SimpleNamespace(
-        c=c, shape=shape_of(c, c["engine"]["page_size"]), peaks=peaks,
+        c=c, work=family(c).work(c, c["engine"]["page_size"]), peaks=peaks,
         trace=tr, t0=t0, t1=t1, e2e=e2e,
         window_iters=[it for it in out.iters if it.t1 <= out.seconds],
         traced_iters=[it for it in out.iters if it.t0 >= a and it.t1 <= b])
@@ -107,7 +105,7 @@ def run_cell(workload: str, cell: dict, seed: int, seconds: float,
     c = load_config(cell["config"])
     mix = load_mix(cell["traffic"])
     sess = sess or Session(c)
-    params = program_params(c, seed)
+    params = family(c).program_params(c, seed)
     jax.block_until_ready(params)
     eng = sess.engine_for(params, seed)
     del params

@@ -1,14 +1,18 @@
-"""Configuration files of the benchmark and their mapping onto the program.
+"""Configuration files of the benchmark and the architecture families.
 
 ``bench/configs/<name>.json`` holds one model configuration as it is run,
 under the published ``config.json`` key names, plus the engine settings of
-its cells.  ``program_config`` builds the program's own ``ModelConfig``
-from those numbers, so the file, not the program's registry, decides what
-is served.
+its cells.  Its ``"family"`` key (``llama`` where absent) names the module
+``bench/families/<family>.py`` that maps those numbers onto the program
+(``program_config``, ``program_params``), holds the float32 reference
+(``logits``) and counts a step's work (``work``), so the file, not the
+program's registry, decides what is served, and a new architecture is a
+new family file.
 """
 from __future__ import annotations
 
-import dataclasses
+import functools
+import importlib.util
 import json
 import os
 import sys
@@ -82,20 +86,24 @@ def cell_metrics(workload: str, trace: bool) -> list:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def head_dim(c: dict) -> int:
-    return c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+FAMILIES = BENCH / "families"
+FAMILY_API = ("program_config", "program_params", "logits", "work")
 
 
-def program_config(c: dict):
-    """The program's ``ModelConfig`` with every size taken from ``c``."""
-    from repro.configs import get_config
-    base = get_config(c["repo_config"])
-    return dataclasses.replace(
-        base,
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=head_dim(c),
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        tie_embeddings=c["tie_word_embeddings"], norm_eps=c["rms_norm_eps"],
-        rope_theta=c["rope_theta"], dtype=c["torch_dtype"],
-        act=c["hidden_act"])
+def family(c: dict):
+    """The architecture family module that configuration ``c`` names."""
+    return _load_family(FAMILIES / f"{c.get('family', 'llama')}.py")
+
+
+@functools.cache
+def _load_family(path: Path):
+    if not path.is_file():
+        raise FileNotFoundError(f"no architecture family module {path}")
+    spec = importlib.util.spec_from_file_location(
+        "bench_family_" + path.stem, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {', '.join(missing)}")
+    return mod

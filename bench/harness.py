@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from config import family
 from stats import percentile, weighted_service
 
 SPANS = ("submit", "admit", "prepare", "plan", "mixed_step", "lifecycle")
@@ -147,11 +148,10 @@ class Session:
     config, cost model and trained predictor."""
 
     def __init__(self, c: dict):
-        from config import program_config
         from repro.launch.serve import build_predictor
         from repro.serving.costmodel import A100_80G, CostModel
         self.engine = c["engine"]
-        self.cfg = program_config(c)
+        self.cfg = family(c).program_config(c)
         # the scheduler's cost model and the predictor, as the launcher
         # builds them; the predictor trains from a fixed seed, as a
         # deployment would train it once

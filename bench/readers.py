@@ -1,7 +1,9 @@
 """Shared arithmetic of the per-layer metric readers in ``bench/metrics``.
 
 Each reader gets one traced run (``cell.per_layer`` builds it) and
-returns a number, or None where the run gives it nothing to read.
+returns a number, or None where the run gives it nothing to read.  The
+counts of a step's work come from the configuration's architecture family
+(``run.work``, ``bench/families/<family>.py``).
 """
 from __future__ import annotations
 
@@ -9,14 +11,16 @@ import math
 
 import tracefile
 from harness import SCHED_SPANS
-from work import attn_bytes, attn_flops, least_time, step_flops
+from work import least_time
 
 STEP_MODULE = "_paged_decode_step"
-# the paged kernel's device operations.  In a TPU trace the Pallas kernel
-# is a ``tpu_custom_call`` instruction named after its jitted wrapper
-# (``%paged_attention.N``; the kernel functions' own names do not
-# appear), and the split-K combine is the fusions that read its outputs
-# (``%pallas_call.N`` operands)
+# the paged kernel's device operations, which every family shares (a
+# family's ``work`` may add its own kernels' names, never take these
+# away).  In a TPU trace a Pallas kernel is a ``tpu_custom_call``
+# instruction named after its ``pallas_call`` (``%paged_attention_live.N``;
+# the kernel functions' own names do not appear); an unnamed call is
+# ``%pallas_call.N``, and the label of an op that reads its output names
+# it too
 KERNEL_NAMES = ("tpu_custom_call", "%paged_attention", "%pallas_call")
 
 
@@ -55,7 +59,7 @@ def step_mfu(run):
                               run.t1) * 1e-9
     if not its or dev <= 0:
         return None
-    flops = sum(step_flops(run.shape, it.ctxs, it.n_logits) for it in its)
+    flops = sum(run.work.step_flops(it.ctxs, it.n_logits) for it in its)
     return 100.0 * flops / (dev * run.peaks["bf16_flops_per_s"])
 
 
@@ -66,12 +70,13 @@ def paged_attn_roofline(run):
     its = run.traced_iters
     if not its or not run.trace.ops:
         return None
-    dev = tracefile.kernel_ns(run.trace.ops[0], KERNEL_NAMES, run.t0,
-                              run.t1) * 1e-9
+    dev = tracefile.kernel_ns(run.trace.ops[0],
+                              KERNEL_NAMES + run.work.kernel_names,
+                              run.t0, run.t1) * 1e-9
     if dev <= 0:
         return None
-    need = sum(least_time(attn_flops(run.shape, it.ctxs),
-                          attn_bytes(run.shape, it.ctxs, it.owners),
+    need = sum(least_time(run.work.attn_flops(it.ctxs),
+                          run.work.attn_bytes(it.ctxs, it.owners),
                           run.peaks["bf16_flops_per_s"],
                           run.peaks["hbm_bytes_per_s"])[0] for it in its)
     return 100.0 * need / dev

@@ -17,7 +17,7 @@ import json
 import sys
 import time
 
-from config import gate, load_config, peaks_table, setup
+from config import family, gate, load_config, peaks_table, setup
 
 
 def main():
@@ -34,7 +34,6 @@ def main():
         sys.exit(refusal)
     from harness import CompileCount, Session, end_to_end, free, serve
     from traffic import generate, load_mix
-    from weights import program_params
 
     c = load_config(args.config)
     sess = Session(c)
@@ -45,7 +44,7 @@ def main():
         for g in mix["groups"]:
             if g["name"] == args.group:
                 g["rate_per_s"] = rate
-        params = program_params(c, args.seed)
+        params = family(c).program_params(c, args.seed)
         eng = sess.engine_for(params, args.seed)
         del params
         sess.warm_up(eng)

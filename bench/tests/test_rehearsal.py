@@ -13,11 +13,12 @@ import pytest
 import cell as cell_mod
 import config
 import harness
-from config import load_json
+from config import family, load_json
 from conftest import DATA
 from faults import planted
 from traffic import generate
-from weights import program_params
+
+program_params = family({"family": "llama"}).program_params
 
 SEED = 2**32 + 17
 SECONDS = 3.0

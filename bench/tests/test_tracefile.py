@@ -7,8 +7,9 @@ import pytest
 import readers
 import tracefile
 from conftest import DATA
+from config import family
 from harness import Iteration
-from work import Shape, attn_bytes, attn_flops, least_time, step_flops
+from work import least_time
 
 T0, T1 = 0, 1200
 
@@ -54,21 +55,23 @@ def test_top_ops_name_instructions_and_skip_control_flow(tr):
 
 
 def test_readers_on_the_recorded_trace(tr):
-    shape = Shape(layers=2, d_model=8, d_ff=16, heads=4, kv_heads=2,
-                  head_dim=2, vocab=10, page=4)
+    c = {"num_hidden_layers": 2, "hidden_size": 8, "intermediate_size": 16,
+         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
+         "vocab_size": 10}
+    w = family(c).work(c, 4)
     it = Iteration(t0=0.0, t1=1.0, ctxs=[0, 1, 2, 7], owners=[1, 1, 1, 2],
                    n_logits=2, spans={"admit": 0.001, "plan": 0.002,
                                       "mixed_step": 0.5})
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
-    run = SimpleNamespace(shape=shape, peaks=peaks, trace=tr, t0=T0, t1=T1,
+    run = SimpleNamespace(work=w, peaks=peaks, trace=tr, t0=T0, t1=T1,
                           window_iters=[it, it], traced_iters=[it])
     assert readers.device_idle_share(run) == pytest.approx(
         100 * (1 - 650 / 1200))
     assert readers.sched_ms_per_iter(run) == pytest.approx(3.0)
     assert readers.step_mfu(run) == pytest.approx(
-        100 * step_flops(shape, it.ctxs, 2) / (900e-9 * 1e12))
-    need = least_time(attn_flops(shape, it.ctxs),
-                      attn_bytes(shape, it.ctxs, it.owners), 1e12, 1e9)[0]
+        100 * w.step_flops(it.ctxs, 2) / (900e-9 * 1e12))
+    need = least_time(w.attn_flops(it.ctxs),
+                      w.attn_bytes(it.ctxs, it.owners), 1e12, 1e9)[0]
     assert readers.paged_attn_roofline(run) == pytest.approx(
         100 * need / 350e-9)
 

@@ -2,9 +2,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from config import load_json
+from config import family, load_json
 from conftest import DATA
-from weights import base_key, layer_weights, program_params
+from weights import base_key
+
+_llama = family({"family": "llama"})
+layer_weights, program_params = _llama._layer_weights, _llama.program_params
 
 
 def test_stacked_layers_equal_each_layer_drawn_alone():

@@ -1,8 +1,14 @@
 """Operation and byte counts of one step, against hand-computed shapes."""
 import pytest
 
-from work import (Shape, attn_bytes, attn_flops, layer_matmul_params,
-                  least_time, shape_of, step_flops)
+from config import family
+from work import least_time
+
+_llama = family({"family": "llama"})
+Shape, shape_of = _llama._Shape, _llama._shape_of
+attn_bytes, attn_flops = _llama._attn_bytes, _llama._attn_flops
+layer_matmul_params, step_flops = (_llama._layer_matmul_params,
+                                   _llama._step_flops)
 
 S = Shape(layers=2, d_model=8, d_ff=16, heads=4, kv_heads=2, head_dim=2,
           vocab=10, page=4)

@@ -22,7 +22,8 @@ Needs the chip.  In one process, over the cell's configuration and mix:
    the window; the idle gaps named by the program's spans and split
    among them; how much of each ``mixed_step`` the ``step.*`` spans
    cover; the light requests' queue waits against their TTFT; and the
-   device ops that ``readers.KERNEL_NAMES`` matches.  The drained
+   device ops that the paged kernel's names match (``readers.KERNEL_NAMES``
+   and the family's own, from ``work``).  The drained
    records (``steps.json``), the recorder's trace (``flight.json``) and
    an excerpt of the profile around its longest idle gap
    (``excerpt.json``, ``tracefile.from_json``'s form) are written beside
@@ -36,7 +37,8 @@ import shutil
 import sys
 import time
 
-from config import ROOT, gate, load_cell, load_config, peaks_table, setup
+from config import (ROOT, family, gate, load_cell, load_config, peaks_table,
+                    setup)
 
 # light requests due this close to the window's end wait through the
 # drain, not the window: ``light_waits`` also reads the share without them
@@ -52,9 +54,8 @@ def window(sess, c, mix, seed, seconds, compiles, traced, observer=None,
     import jax
     from harness import serve
     from traffic import generate
-    from weights import program_params
     from repro.serving.telemetry import StepTracer
-    params = program_params(c, seed)
+    params = family(c).program_params(c, seed)
     jax.block_until_ready(params)
     eng = sess.engine_for(params, seed)
     del params
@@ -228,8 +229,10 @@ def profile(workload, sess, c, mix, seed, seconds, compiles, peaks):
     gaps = st.top_gaps(ops, program, spans, t0, t1)
     cover = [st.covered(program, s) for s in spans
              if s[0] == "mixed_step" and s[1] >= t0 and s[2] <= t1]
+    names = readers.KERNEL_NAMES + family(c).work(
+        c, c["engine"]["page_size"]).kernel_names
     kernel = sorted({o[0].split(" = ")[0] for o in tracefile.work_ops(ops)
-                     if tracefile.matches(o, readers.KERNEL_NAMES)
+                     if tracefile.matches(o, names)
                      and o[2] > t0 and o[1] < t1})
     line = {
         "phase": "profile", "seed": seed, "metrics": metrics,

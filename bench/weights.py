@@ -1,7 +1,9 @@
-"""Weights from the seed, in the program's parameter layout.
+"""Weights from the seed: the draw every architecture family shares.
 
-Every leaf is drawn from its own key, and every layer of a stacked leaf
-from ``fold_in(leaf key, layer)``, so the reference can draw one layer's
+A family lists one layer's leaves and lays them out as the program wants
+them (``bench/families/<family>.py``); this module draws them.  Every leaf
+is drawn from its own key, and every layer of a stacked leaf from
+``fold_in(leaf key, layer)``, so the reference can draw one layer's
 weights again without holding the rest (``layer_weights``).  The values
 are drawn in float32 and rounded once to the served type: the program
 gets that type, the reference the same rounded numbers in float32.
@@ -13,12 +15,8 @@ the comparison).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
-from config import head_dim
 
 
 def base_key(seed: int):
@@ -32,25 +30,6 @@ def base_key(seed: int):
         key = jax.random.fold_in(key, rest & 0x7FFFFFFF)
         rest >>= 31
     return key
-
-
-def layer_leaves(c: dict):
-    """(group, name, shape, scale) of one decoder layer; scale None marks
-    a norm scale."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    h, hkv, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                  head_dim(c))
-    return [
-        ("ln1", "scale", (d,), None),
-        ("attn", "wq", (d, h, hd), d ** -0.5),
-        ("attn", "wk", (d, hkv, hd), d ** -0.5),
-        ("attn", "wv", (d, hkv, hd), d ** -0.5),
-        ("attn", "wo", (h, hd, d), (h * hd) ** -0.5),
-        ("ln2", "scale", (d,), None),
-        ("ffn", "w_in", (d, f), d ** -0.5),
-        ("ffn", "w_gate", (d, f), d ** -0.5),
-        ("ffn", "w_out", (f, d), f ** -0.5),
-    ]
 
 
 def _draw(key, shape, scale):
@@ -70,12 +49,14 @@ def _leaf_key(key, i: int):
     return jax.random.fold_in(key, 1000 + i)
 
 
-def layer_weights(c: dict, key, layer, dtype) -> dict:
+def layer_weights(c: dict, leaves, key, layer, dtype) -> dict:
     """Layer ``layer``'s weights (traceable in ``layer``), rounded to the
-    served type and returned in ``dtype``."""
+    served type and returned in ``dtype``.  ``leaves`` lists the layer's
+    (group, name, shape, scale), scale None marking a norm scale; leaf i
+    is drawn from ``_leaf_key(key, i)``."""
     served = jnp.dtype(c["torch_dtype"])
     out: dict = {}
-    for i, (group, name, shape, scale) in enumerate(layer_leaves(c)):
+    for i, (group, name, shape, scale) in enumerate(leaves):
         k = jax.random.fold_in(_leaf_key(key, i), layer)
         w = _draw(k, shape, scale).astype(served).astype(dtype)
         out.setdefault(group, {})[name] = w
@@ -94,24 +75,12 @@ def embed_weights(c: dict, key, dtype) -> dict:
     return rounded, final.astype(served).astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _program_params(frozen, key):
-    c = dict(frozen)
-    served = jnp.dtype(c["torch_dtype"])
-    layers = jax.vmap(lambda i: layer_weights(c, key, i, served))(
-        jnp.arange(c["num_hidden_layers"]))
-    emb, final = embed_weights(c, key, served)
-    return {"embed": emb, "final_norm": {"scale": final},
-            "stages": {"stage_0": layers}}
-
-
-def freeze(c: dict):
-    """A hashable view of the numbers and flags of ``c`` (static jit
-    argument)."""
-    return tuple(sorted((k, v) for k, v in c.items()
-                        if isinstance(v, (int, float, str, bool))))
-
-
-def program_params(c: dict, seed: int):
-    """The whole parameter tree, made on the device in one jitted call."""
-    return _program_params(freeze(c), base_key(seed))
+def freeze(c):
+    """A hashable view of ``c`` (static jit argument): its items sorted by
+    key, lists as tuples in their order and dicts as tuples of sorted
+    items, so ``dict(freeze(c))`` gives every key, nested ones whole."""
+    if isinstance(c, dict):
+        return tuple(sorted((k, freeze(v)) for k, v in c.items()))
+    if isinstance(c, (list, tuple)):
+        return tuple(freeze(v) for v in c)
+    return c
