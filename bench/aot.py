@@ -11,47 +11,69 @@ what loading the program reserves beside its arguments.
 """
 import sys
 
+import numpy as np
+
 from config import setup
 
 jax = setup(cache=False)
-import jax.numpy as jnp  # noqa: E402
+
+
+class _Captured(Exception):
+    """Raised by the stand-in step once it holds the step's arguments."""
+
+
+def step_arguments(c: dict, rows: int, tabs: int, sharding) -> tuple:
+    """The arguments the engine hands its fused step for one padding step
+    of ``rows`` rows over ``tabs`` table rows (``harness.padding_step``),
+    as shapes placed by ``sharding``, statics as they are: the engine is
+    built and stepped under ``jax.eval_shape`` with a stand-in step that
+    records what it is given, so the weights, the cache state, whatever
+    its layout, and the engine's own page count come from the program,
+    and nothing is allocated."""
+    from config import family
+    from harness import Session, padding_step
+    from repro.serving import engine as mod
+    sess, real, got = Session(c), mod._paged_decode_step, []
+
+    def stand_in(*args):
+        got.append(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding)
+            if isinstance(a, (jax.Array, np.ndarray)) else a, args))
+        raise _Captured
+
+    def one_step():
+        params = family(c).program_params(c, 0)
+        padding_step(sess.engine_for(params, 0), rows, tabs)
+
+    mod._paged_decode_step = stand_in
+    try:
+        jax.eval_shape(one_step)
+    except _Captured:
+        pass
+    finally:
+        mod._paged_decode_step = real
+    return got[0]
 
 
 def main(name: str):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from config import family, load_config
+    from config import load_config
     from harness import buckets, next_pow2
     from repro.kernels import ops
     from repro.serving.engine import _paged_decode_step
-    from repro.serving.kv_cache import make_pools
 
     ops._interpret = lambda: False        # tracing sees the CPU backend
     c = load_config(name)
-    fam = family(c)
-    cfg, e = fam.program_config(c), c["engine"]
+    e = c["engine"]
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
-
-    def on(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=chip), tree)
-
-    params = on(jax.eval_shape(lambda: fam.program_params(c, 0)))
     rows, tabs = max(buckets(e))
     width = next_pow2(-(-e["max_len"] // e["page_size"]))
-    i32 = jnp.int32
-    ragged = on((jax.ShapeDtypeStruct((rows,), i32),
-                 jax.ShapeDtypeStruct((rows,), i32),
-                 jax.ShapeDtypeStruct((tabs, width), i32),
-                 jax.ShapeDtypeStruct((rows,), i32)))
     budget = e["kv_budget_tokens"]
-    pages = -(-budget // e["page_size"]) + 1
-    pools = on(jax.eval_shape(lambda: make_pools(
-        cfg.n_layers, pages, e["page_size"], cfg.n_kv_heads,
-        cfg.resolved_head_dim(), jnp.dtype(cfg.dtype))))
-    m = _paged_decode_step.lower(params, *ragged, *pools, None, None,
-                                 cfg, e["page_size"]).compile()
+    m = _paged_decode_step.lower(
+        *step_arguments(c, rows, tabs, chip)).compile()
     m = m.memory_analysis()
     gib = 2.0 ** 30
     total = (m.argument_size_in_bytes + m.output_size_in_bytes

@@ -4,8 +4,8 @@ that serves through it has to come out not correct.
 
 - ``token_altered``: every sampled token is the next id after the
   greedy choice;
-- ``state_unchanged``: the fused step returns the K/V pools it was given,
-  so no iteration's K/V reaches later ones;
+- ``state_unchanged``: the fused step returns the cache state it was
+  given, so no iteration's K/V reaches later ones;
 - ``half_left_out``: the fused step serves the second half of its rows
   as token 0 (the first half as given).
 
@@ -32,9 +32,11 @@ def _step_fault(fault):
     return plant
 
 
-def _state_unchanged(real, params, tokens, ctx, tables, rmap, kp, vp, *a):
-    out = real(params, tokens, ctx, tables, rmap, kp, vp, *a)
-    return (out[0], kp, vp) + tuple(out[3:])
+def _state_unchanged(real, params, tokens, ctx, tables, rmap, *rest):
+    # the step returns its logits, then its cache state in the order the
+    # state came in, right after the row map
+    out = real(params, tokens, ctx, tables, rmap, *rest)
+    return (out[0], *rest[:len(out) - 1])
 
 
 def _half_left_out(real, params, tokens, ctx, tables, rmap, *a):

@@ -24,6 +24,7 @@ import gc
 import math
 import time
 from dataclasses import dataclass, field
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -174,27 +175,14 @@ class Session:
             slo_budget=e["slo_budget"], kv_quant=kv_quant, seed=seed)
 
     def warm_up(self, eng) -> int:
-        """Run the fused step once at every reachable shape, with padding
-        rows only (token 0 at position 0 of the scratch page), so that the
+        """Run the fused step once at every reachable shape, so that the
         window compiles nothing.  Returns how many shapes were new here."""
-        from repro.serving.engine import _paged_decode_step
         n_new = 0
-        width = next_pow2(eng.pool.pages_needed(eng.max_len))
         for rows, tabs in buckets(self.engine):
             key = (rows, tabs, eng.kv_quant)
             if key in self.warm:
                 continue
-            z = np.zeros(rows, np.int32)
-            table = np.full((tabs, width), eng._scratch_page, np.int32)
-            rmap = np.full(rows, tabs - 1, np.int32)
-            out = _paged_decode_step(
-                eng.params, *eng._place((z, z, table, rmap)), eng.k_pools,
-                eng.v_pools, eng.k_scales, eng.v_scales, eng.cfg,
-                eng.pool.page_size)
-            eng.k_pools, eng.v_pools = out[1:3]
-            if eng.kv_quant:
-                eng.k_scales, eng.v_scales = out[3:]
-            np.asarray(out[0][:1])
+            padding_step(eng, rows, tabs)
             self.warm.add(key)
             n_new += 1
         # the predictor's first call traces its ops
@@ -203,6 +191,29 @@ class Session:
             rid=-1, client="warm", arrival=0.0, prompt_len=64,
             output_len=1, keywords=("chat",)))
         return n_new
+
+
+def padding_step(eng, rows: int, tabs: int):
+    """One fused step of ``rows`` padding rows (token 0 at position 0 of
+    the scratch page) over ``tabs`` table rows.  It goes down through the
+    engine's own ``_run_mixed_paged``, which hands the step the engine's
+    cache state and keeps the state the step returns, whatever its
+    layout; only the packed inputs, ``_pack_mixed``'s part, are this
+    shape's."""
+    width = next_pow2(eng.pool.pages_needed(eng.max_len))
+    z = np.zeros(rows, np.int32)
+    table = np.full((tabs, width), eng._scratch_page, np.int32)
+    rmap = np.full(rows, tabs - 1, np.int32)
+    args = (eng.params, *eng._place((z, z, table, rmap)))
+    # as _pack_mixed returns them: (step args, {rid: chunk's last row},
+    # chunk rows, (real rows, each launched row's position, table width))
+    eng._pack_mixed = lambda plan, decoding: (args, {}, 0,
+                                              (0, [0] * rows, width))
+    try:
+        # one decode row, whose logits (row 0) are read back
+        eng._run_mixed_paged([], [SimpleNamespace(rid=None)])
+    finally:
+        del eng._pack_mixed
 
 
 def to_requests(specs):
@@ -419,10 +430,11 @@ def check(c: dict, seed: int, out: Served, picked, control=False) -> dict:
 
 
 def free(eng) -> float:
-    """Drop the engine's device state before the reference runs; returns
-    the host seconds of the full garbage collection that frees it."""
-    eng.k_pools = eng.v_pools = eng.k_scales = eng.v_scales = None
-    eng.params = None
+    """Drop everything the engine instance holds, its weights and its
+    cache state whatever their layout, before the reference runs; returns
+    the host seconds of the full garbage collection that frees it.  The
+    engine is of no further use."""
+    vars(eng).clear()
     t = time.perf_counter()
     gc.collect()
     return time.perf_counter() - t

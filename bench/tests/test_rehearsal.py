@@ -8,6 +8,7 @@ The configuration is float32, so a sound run's served tokens are the
 reference's first choices and the limit can be tight.
 """
 import jax
+import jax.numpy as jnp
 import pytest
 
 import cell as cell_mod
@@ -55,6 +56,27 @@ def test_window_compiles_nothing_and_reads_every_metric(session):
     assert e2e["_n"]["ttft"] > 0 and e2e["_n"]["gaps"] > 0
     assert 0 < e2e["service_jain"] <= 1 and e2e["tokens_per_s"] > 0
     assert harness.sampleable_tokens(out) > 0
+
+
+@pytest.mark.parametrize("kv_quant", [False, True])
+def test_warm_up_and_free_name_no_cache_layout(kv_quant):
+    """Warm-up and ``free`` go through the engine without naming its
+    cache state: with bf16 or int8 pages alike every shape is warmed, a
+    step at a warmed shape traces nothing new, and ``free`` leaves no
+    array of the engine alive."""
+    from repro.serving.engine import _paged_decode_step
+    c = load_json(DATA / "tiny.json")
+    sess = harness.Session(c)
+    before = len(jax.live_arrays())
+    eng = sess.engine_for(program_params(c, SEED), SEED, kv_quant=kv_quant)
+    shapes = harness.buckets(c["engine"])
+    assert sess.warm_up(eng) == len(shapes)
+    assert any(a.dtype == jnp.int8 for a in jax.live_arrays()) == kv_quant
+    traced = _paged_decode_step._cache_size()
+    harness.padding_step(eng, *max(shapes))
+    assert _paged_decode_step._cache_size() == traced
+    harness.free(eng)
+    assert len(jax.live_arrays()) == before
 
 
 def test_sound_run_is_correct_and_the_control_is_not(session, monkeypatch):

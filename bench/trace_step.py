@@ -59,12 +59,14 @@ def window(sess, c, mix, seed, seconds, compiles, traced, observer=None,
     jax.block_until_ready(params)
     eng = sess.engine_for(params, seed)
     del params
+    # warm-up steps go through the engine's own step path: the tracer
+    # comes on after them, so that they add to no record
+    sess.warm_up(eng)
     if traced:
         eng.tracer = StepTracer(on=True)
     if observer is not None:
         eng.core.observer = observer
         observer.bind_core(eng.core)
-    sess.warm_up(eng)
     specs = generate(mix, seed, seconds, c["vocab_size"])
     return eng, serve(eng, specs, seconds, compiles, **serve_kw)
 
